@@ -33,6 +33,7 @@ from repro.core import hfuse
 from repro.core.binding import BindingRegistry, State
 from repro.core.op_spec import OpSpec
 from repro.core.planner import FusionPlan, GraphOp
+from repro.distributed import hlo_analysis
 
 
 @dataclass
@@ -58,6 +59,7 @@ class Program:
     steps: list[ProgramStep]
     bindings: BindingRegistry
     graph: tuple[GraphOp, ...]
+    interpret: bool = False             # kernels run in the Pallas interpreter
 
     def __call__(self, state: State) -> State:
         for step in self.steps:
@@ -120,6 +122,10 @@ def compile_plan(plan: FusionPlan, graph: Optional[Sequence[GraphOp]] = None,
     must cover every named operand of every graph op; pass
     ``binding.default_bindings(ops)`` for the synthesized-state form.
     """
+    if not interpret:
+        # the plan priced its bundles with the v5e peaks: compiling it for
+        # a TPU kind with no peak row is refused rather than mis-planned
+        hlo_analysis.chip()
     graph = tuple(graph if graph is not None else (plan.graph or ()))
     if not graph:
         raise ValueError("compile_plan needs the planner graph "
@@ -164,4 +170,5 @@ def compile_plan(plan: FusionPlan, graph: Optional[Sequence[GraphOp]] = None,
             steps.append(ProgramStep(members, call, (op,), False))
         for op in steps[-1].ops:
             bindings.validate(op)
-    return Program(steps=steps, bindings=bindings, graph=graph)
+    return Program(steps=steps, bindings=bindings, graph=graph,
+                   interpret=interpret)

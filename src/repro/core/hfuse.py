@@ -32,10 +32,41 @@ from typing import Optional, Sequence
 
 import jax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 import jax.numpy as jnp
 
-from repro.core.cost_model import Schedule
-from repro.core.op_spec import OpSpec
+from repro.core.cost_model import VMEM_BUDGET, Schedule
+from repro.core.op_spec import Operand, OpSpec
+
+
+def _block_spec(operand: Operand, index_map) -> pl.BlockSpec:
+    if operand.smem:
+        return pl.BlockSpec(memory_space=pltpu.SMEM)
+    return pl.BlockSpec(operand.block_shape, index_map)
+
+
+def _pallas_call(kernel, ops: Sequence[OpSpec], grid: int, in_specs,
+                 out_specs, *, interpret: bool, vmem_limit: Optional[int]):
+    """One pallas_call over ``ops``' operands and scratch.  A compiled
+    (non-interpret) call always states its scoped-VMEM limit: the tuned cap,
+    else the whole planning budget — the 16 MiB compiler default is smaller
+    than bundles the cost model admits."""
+    kwargs = {}
+    if not interpret:
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=int(vmem_limit or VMEM_BUDGET))
+    return pl.pallas_call(
+        kernel,
+        grid=(grid,),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=[jax.ShapeDtypeStruct(o.shape, o.dtype)
+                   for op in ops for o in op.outputs],
+        scratch_shapes=[pltpu.VMEM(shape, dt)
+                        for op in ops for shape, dt in op.scratch],
+        interpret=interpret,
+        **kwargs,
+    )
 
 
 def _bundle_phase_fns(ops: Sequence[OpSpec], sched: Schedule):
@@ -93,9 +124,12 @@ def generate(ops, b=None, sched: Optional[Schedule] = None, *,
 
     n_ins = [len(op.inputs) for op in ops]
     n_outs = [len(op.outputs) for op in ops]
+    n_scr = [len(op.scratch) for op in ops]
     in_off = [sum(n_ins[:i]) for i in range(len(ops) + 1)]
     out_off = [sum(n_outs[:i]) for i in range(len(ops) + 1)]
+    scr_off = [sum(n_scr[:i]) for i in range(len(ops) + 1)]
     n_in_total = in_off[-1]
+    n_io = n_in_total + out_off[-1]
 
     def fused_kernel(*refs):
         t = pl.program_id(0)
@@ -103,40 +137,22 @@ def generate(ops, b=None, sched: Optional[Schedule] = None, *,
             step, active = fns[i]
             ins = refs[in_off[i]:in_off[i + 1]]
             outs = refs[n_in_total + out_off[i]:n_in_total + out_off[i + 1]]
+            scr = refs[n_io + scr_off[i]:n_io + scr_off[i + 1]]
 
             @pl.when(active(t))
-            def _(op=op, step=step, ins=ins, outs=outs):
-                op.body(step(t), *ins, *outs)
+            def _(op=op, step=step, ins=ins, outs=outs, scr=scr):
+                op.body(step(t), *ins, *outs, *scr)
 
     def remap(op_step, operand):
-        return pl.BlockSpec(operand.block_shape,
-                            lambda t, _f=operand.index_map, _s=op_step: _f(_s(t)))
+        return _block_spec(
+            operand, lambda t, _f=operand.index_map, _s=op_step: _f(_s(t)))
 
     in_specs = [remap(fns[i][0], o)
                 for i, op in enumerate(ops) for o in op.inputs]
     out_specs = [remap(fns[i][0], o)
                  for i, op in enumerate(ops) for o in op.outputs]
-    out_shape = [jax.ShapeDtypeStruct(o.shape, o.dtype)
-                 for op in ops for o in op.outputs]
-
-    kwargs = {}
-    if vmem_limit and not interpret and jax.default_backend() == "tpu":
-        try:
-            from jax.experimental.pallas import tpu as pltpu
-            kwargs["compiler_params"] = pltpu.CompilerParams(
-                vmem_limit_bytes=int(vmem_limit))
-        except Exception:
-            pass
-
-    call = pl.pallas_call(
-        fused_kernel,
-        grid=(n_steps,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-        **kwargs,
-    )
+    call = _pallas_call(fused_kernel, ops, n_steps, in_specs, out_specs,
+                        interpret=interpret, vmem_limit=vmem_limit)
 
     def fused(*operands):
         assert len(operands) == n_in_total, (len(operands), n_ins)
@@ -161,17 +177,13 @@ def generate_vfused(*ops, **kw):
 def run_single(op: OpSpec, *, interpret: bool = False):
     """Standalone pallas_call for one OpSpec (used by tests and `native`)."""
     def kernel(*refs):
-        t = pl.program_id(0)
-        op.body(t, *refs)
+        op.body(pl.program_id(0), *refs)
 
-    call = pl.pallas_call(
-        kernel,
-        grid=(op.grid,),
-        in_specs=[pl.BlockSpec(o.block_shape, o.index_map) for o in op.inputs],
-        out_specs=[pl.BlockSpec(o.block_shape, o.index_map) for o in op.outputs],
-        out_shape=[jax.ShapeDtypeStruct(o.shape, o.dtype) for o in op.outputs],
-        interpret=interpret,
-    )
+    call = _pallas_call(
+        kernel, (op,), op.grid,
+        [_block_spec(o, o.index_map) for o in op.inputs],
+        [_block_spec(o, o.index_map) for o in op.outputs],
+        interpret=interpret, vmem_limit=None)
 
     def run(*operands):
         outs = call(*operands)

@@ -8,8 +8,10 @@ block space; the *fused* kernel's grid (core/hfuse.py) partitions / interleaves
 its steps between two ops the way HFUSE partitions the thread space.
 
 Contract for ``body``:
-  body(step, *in_refs, *out_refs) — ``step`` is the op-local grid step
-  (a traced scalar); refs are VMEM blocks selected by the index maps.
+  body(step, *in_refs, *out_refs, *scratch_refs) — ``step`` is the
+  op-local grid step (a traced scalar); refs are VMEM blocks selected by
+  the index maps (SMEM tables for ``Operand.smem``), then one VMEM buffer
+  per ``OpSpec.scratch`` entry, persistent across the op's steps.
   The body must not call pl.program_id itself (the fused kernel owns it).
 """
 from __future__ import annotations
@@ -27,14 +29,40 @@ from repro.distributed.hlo_analysis import HBM_BW, PEAK_FLOPS, RIDGE, VMEM_BYTES
 
 @dataclass(frozen=True)
 class Operand:
-    """One input or output of a fusible op."""
+    """One input or output of a fusible op.
+
+    ``smem=True`` marks a small int32 table (per-slot lengths, chunk
+    offsets, block-table rows) that the kernel reads as scalars: it lives
+    whole in scalar memory, so ``block_shape`` equals ``shape``, the index
+    map is never consulted and it costs no VMEM."""
     shape: tuple[int, ...]
     dtype: Any
     block_shape: tuple[int, ...]
     index_map: Callable[[Any], tuple]      # op-local step -> block indices
+    smem: bool = False
 
     def block_bytes(self) -> int:
-        return int(math.prod(self.block_shape)) * jnp.dtype(self.dtype).itemsize
+        if self.smem:
+            return 0
+        return vmem_bytes_of(self.block_shape, self.dtype)
+
+
+def vmem_bytes_of(shape: Sequence[int], dtype) -> int:
+    """Bytes one VMEM buffer of ``shape`` occupies: the last two dims round
+    up to the (8, 128) tile, which is what the compiler allocates (a
+    (ck, Hkv, 64) cache block takes 128 lanes per row, not 64)."""
+    dims = list(shape)
+    if dims:
+        dims[-1] = -(-dims[-1] // 128) * 128
+    if len(dims) > 1:
+        dims[-2] = -(-dims[-2] // 8) * 8
+    return int(math.prod(dims)) * jnp.dtype(dtype).itemsize
+
+
+def smem_operand(shape: tuple[int, ...]) -> Operand:
+    """A whole int32 array in scalar memory (see ``Operand.smem``)."""
+    return Operand(tuple(shape), jnp.int32, tuple(shape),
+                   lambda s: (0,) * len(shape), smem=True)
 
 
 @dataclass
@@ -67,6 +95,10 @@ class OpSpec:
     # binding then reads and rewrites the same state key.
     in_names: tuple[str, ...] = ()
     out_names: tuple[str, ...] = ()
+    # Persistent VMEM buffers ((shape, dtype) each) handed to the body after
+    # its output refs — carries such as online-softmax statistics that need
+    # no HBM copy.
+    scratch: tuple[tuple[tuple[int, ...], Any], ...] = ()
 
     def __post_init__(self):
         if self.in_names and len(self.in_names) != len(self.inputs):
@@ -83,9 +115,11 @@ class OpSpec:
     # ------------------------------------------------------------------
     @property
     def vmem_bytes(self) -> int:
-        """Per-step working set (single-buffered); a stitched chain's
-        resident intermediate rides in ``extra_vmem_bytes``."""
+        """Per-step working set (single-buffered blocks plus scratch); the
+        body's live intermediates (a stitched chain's resident block, an
+        attention score tile) ride in ``extra_vmem_bytes``."""
         return (sum(o.block_bytes() for o in (*self.inputs, *self.outputs))
+                + sum(vmem_bytes_of(shape, dt) for shape, dt in self.scratch)
                 + self.extra_vmem_bytes)
 
     @property

@@ -231,13 +231,15 @@ class ScheduleCache:
 
 
 def default_cache() -> ScheduleCache:
-    """Process-wide cache at $REPRO_SCHEDULE_CACHE (or ~/.cache/repro/),
-    size-bounded by $REPRO_SCHEDULE_CACHE_MAX (LRU, default 512)."""
+    """Process-wide cache at $REPRO_SCHEDULE_CACHE (or
+    ``<checkout>/.schedule_cache.json``), size-bounded by
+    $REPRO_SCHEDULE_CACHE_MAX (LRU, default 512)."""
     global _DEFAULT
     if _DEFAULT is None:
         path = os.environ.get(
             "REPRO_SCHEDULE_CACHE",
-            str(Path.home() / ".cache" / "repro" / "schedule_cache.json"))
+            str(Path(__file__).resolve().parents[3]
+                / ".schedule_cache.json"))
         bound = int(os.environ.get("REPRO_SCHEDULE_CACHE_MAX", "512"))
         _DEFAULT = ScheduleCache(path, max_entries=bound or None)
     return _DEFAULT

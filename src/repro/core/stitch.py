@@ -191,6 +191,7 @@ def stitch(producer: OpSpec, consumer: OpSpec, operand: str) -> OpSpec:
     pout = producer.outputs[0]
     cin = consumer.inputs[sidx]
     n_pi, n_ci = len(producer.inputs), len(consumer.inputs)
+    n_co, n_ps = len(consumer.outputs), len(producer.scratch)
     reshape_to = (None if pout.block_shape == cin.block_shape
                   else cin.block_shape)
     p_body, c_body = producer.body, consumer.body
@@ -198,16 +199,17 @@ def stitch(producer: OpSpec, consumer: OpSpec, operand: str) -> OpSpec:
     def body(step, *refs):
         pin = refs[:n_pi]
         cin_ext = refs[n_pi:n_pi + n_ci - 1]
-        couts = refs[n_pi + n_ci - 1:]
+        couts = refs[n_pi + n_ci - 1:n_pi + n_ci - 1 + n_co]
+        scr = refs[n_pi + n_ci - 1 + n_co:]
         cap = _CaptureRef(pout.block_shape, pout.dtype)
-        p_body(step, *pin, cap)
+        p_body(step, *pin, cap, *scr[:n_ps])
         if cap.value is None:
             raise RuntimeError(
                 f"{producer.name}: body never wrote its output block")
         val = cap.value if reshape_to is None else cap.value.reshape(
             reshape_to)
         crefs = (*cin_ext[:sidx], _ValueRef(val), *cin_ext[sidx:])
-        c_body(step, *crefs, *couts)
+        c_body(step, *crefs, *couts, *scr[n_ps:])
 
     def shrink(factor: int) -> Optional[OpSpec]:
         ps = shrink_blocks(producer, factor)
@@ -233,5 +235,7 @@ def stitch(producer: OpSpec, consumer: OpSpec, operand: str) -> OpSpec:
         + consumer.in_names[sidx + 1:],
         out_names=consumer.out_names,
         chain=(producer.name, consumer.name),
-        extra_vmem_bytes=pout.block_bytes(),
+        scratch=producer.scratch + consumer.scratch,
+        extra_vmem_bytes=(pout.block_bytes() + producer.extra_vmem_bytes
+                          + consumer.extra_vmem_bytes),
     )

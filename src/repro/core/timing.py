@@ -36,11 +36,12 @@ from repro.core.op_spec import OpSpec
 
 
 def resolve_backend(backend: str = "auto") -> str:
-    """'auto' -> the JAX default backend, with CPU mapped to 'interpret'."""
+    """'auto' -> the JAX default backend; only the CPU (the test backend)
+    maps to the 'interpret' proxy."""
     if backend != "auto":
         return backend
     be = jax.default_backend()
-    return be if be in ("tpu", "gpu") else "interpret"
+    return "interpret" if be == "cpu" else be
 
 
 def synth_inputs(ops: Sequence[OpSpec], seed: int = 0) -> list[jax.Array]:

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.compat import shard_map
 
 
 def quantize_int8(x: jax.Array):
@@ -91,11 +90,11 @@ def pod_compressed_grads(loss_fn: Callable, mesh: Mesh):
         # params replicated over pod (P()); batch dim-0 manual over pod —
         # its data-axis sharding stays auto.
         batch_specs = jax.tree.map(lambda x: P("pod"), batch)
-        f = shard_map(local_grads, mesh=mesh,
-                      in_specs=(jax.tree.map(lambda _: P(), params),
-                                batch_specs),
-                      out_specs=(P(), P(), jax.tree.map(lambda _: P(), params)),
-                      axis_names={"pod"}, check_vma=False)
+        f = jax.shard_map(
+            local_grads, mesh=mesh,
+            in_specs=(jax.tree.map(lambda _: P(), params), batch_specs),
+            out_specs=(P(), P(), jax.tree.map(lambda _: P(), params)),
+            axis_names={"pod"}, check_vma=False)
         return f(params, batch)
 
     return wrapped

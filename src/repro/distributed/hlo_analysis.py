@@ -9,8 +9,11 @@ Sources:
     bidirectional-ring factors per op kind and the replica-group size parsed
     from the op.
 
-TPU v5e hardware constants (targets; this container is CPU-only):
-  197 TFLOP/s bf16 / chip, 819 GB/s HBM, ~50 GB/s/link ICI, ~128MiB VMEM.
+Chip peaks live in ``CHIPS``, keyed by ``jax.Device.device_kind``.  The
+planner and cost model plan for ``PLANNING_TARGET`` (a v5e chip); the
+module-level constants below are that row.  ``chip(device)`` resolves the
+row for the device a program will run on: a CPU plans for the target, and a
+TPU whose kind has no row is an error, never a silent default.
 """
 from __future__ import annotations
 
@@ -18,10 +21,45 @@ import dataclasses
 import re
 from typing import Optional
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s / chip
-ICI_BW = 50e9                # bytes/s / link (per-chip effective budget)
-VMEM_BYTES = 128 * 2 ** 20
+
+@dataclasses.dataclass(frozen=True)
+class Chip:
+    peak_flops: float        # bf16 FLOP/s per chip
+    hbm_bw: float            # HBM bytes/s per chip
+    ici_bw: float            # chip-to-chip bytes/s per link
+    vmem_bytes: int          # on-core vector memory
+    source: str
+
+
+CHIPS = {
+    # 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s ICI per chip (taken as
+    # ~50 GB/s per link of the 2-D torus), 128 MiB VMEM per core
+    "TPU v5 lite": Chip(197e12, 819e9, 50e9, 128 * 2 ** 20,
+                        "Google Cloud documentation, 'TPU v5e'"),
+}
+PLANNING_TARGET = "TPU v5 lite"
+
+
+def chip(device=None) -> Chip:
+    """Peaks of ``device`` (default: the first JAX device).  CPU devices
+    plan for ``PLANNING_TARGET``; any other kind must have a row."""
+    import jax
+    device = device or jax.devices()[0]
+    if device.platform == "cpu":
+        return CHIPS[PLANNING_TARGET]
+    try:
+        return CHIPS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak row for device kind {device.device_kind!r}; add it to "
+            "hlo_analysis.CHIPS with its source") from None
+
+
+_TARGET = CHIPS[PLANNING_TARGET]
+PEAK_FLOPS = _TARGET.peak_flops
+HBM_BW = _TARGET.hbm_bw
+ICI_BW = _TARGET.ici_bw
+VMEM_BYTES = _TARGET.vmem_bytes
 RIDGE = PEAK_FLOPS / HBM_BW  # ~240 flop/byte
 
 _DTYPE_BYTES = {

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.distributed.compat import shard_map
 
 
 def gpipe(stage_fn: Callable, n_stages: int, axis: str = "pod"):
@@ -84,7 +83,7 @@ def pipeline_over_pods(stage_fn: Callable, mesh: Mesh, n_stages: int):
             ys = jnp.where(stage == n_stages - 1, ys, jnp.zeros_like(ys))
             return jax.lax.psum(ys, "pod")
 
-        f = shard_map(
+        f = jax.shard_map(
             body, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P("pod"), stage_params_stacked),
                       P()),

@@ -185,28 +185,11 @@ def data_shards() -> int:
 def _manual_axes() -> frozenset:
     """Mesh axes currently under manual shard_map control (e.g. 'pod' inside
     the int8-compressed gradient region) — constraints must not mention them."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is None or am.empty:
-            return frozenset()
-        return frozenset(n for n, t in zip(am.axis_names, am.axis_types)
-                         if str(t) == "Manual")
-    except Exception:
-        pass
-    # jax 0.4.x: no abstract mesh — a shard_map-manual axis is bound in the
-    # trace's axis env exactly like a pmap axis, so probe each mesh axis
-    mesh = _CTX.mesh
-    if mesh is None:
+    am = jax.sharding.get_abstract_mesh()
+    if am is None or am.empty:
         return frozenset()
-    from jax._src import core as _core
-    manual = set()
-    for name in mesh.axis_names:
-        try:
-            _core.axis_frame(name)
-            manual.add(name)
-        except Exception:
-            continue
-    return frozenset(manual)
+    return frozenset(n for n, t in zip(am.axis_names, am.axis_types)
+                     if t == jax.sharding.AxisType.Manual)
 
 
 def shard(x: jax.Array, logical: Sequence[Optional[str]]) -> jax.Array:

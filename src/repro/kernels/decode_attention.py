@@ -11,7 +11,7 @@ scratch) so the op composes under core/hfuse.generate.
 Paged form (``block_table=(num_blocks, block_size)``): the k/v operands are
 a flat block arena ``(num_blocks, block_size, Hkv, D)`` shared by every
 slot, and a per-slot block table rides as one more small int32 operand
-("bt", ``(B, max_blocks)``, fetched batch-major like "len").  Each kv-chunk
+("bt", ``(B, max_blocks)``, in scalar memory like "len").  Each kv-chunk
 step gathers its ``ck // block_size`` pages from the arena by table lookup
 — the memory-intensive indirection the serve engine pairs with
 compute-bound GEMMs in one fused launch (serve/kv_pool.py owns the arena).
@@ -27,18 +27,37 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.op_spec import MIN_BLOCK_ROWS, OpSpec, Operand
+from repro.core.op_spec import MIN_BLOCK_ROWS, OpSpec, Operand, smem_operand
 
 NEG_INF = -1e30
 
 
-def gather_pages(ref, bt, first_page: int, npages: int):
-    """Assemble one (npages * block_size, ...) kv-chunk from the arena
-    ``ref`` by looking pages ``bt[first_page : first_page + npages]`` up in
-    the (already loaded) block-table row ``bt``.  ``first_page`` may be a
-    traced scalar; ``npages`` is static."""
-    pages = [ref[pl.ds(bt[first_page + p], 1)][0] for p in range(npages)]
+def gather_pages(ref, bt_ref, row, first_page, npages: int, g: int):
+    """KV head ``g`` of one (npages * block_size, D) kv-chunk, assembled
+    from the arena ``ref`` by looking pages ``first_page ..`` up in row
+    ``row`` of the SMEM block table ``bt_ref``.  ``row`` and ``first_page``
+    may be traced scalars; ``npages`` and ``g`` are static."""
+    pages = [ref[pl.ds(bt_ref[row, first_page + p], 1), :, g, :][0]
+             for p in range(npages)]
     return pages[0] if npages == 1 else jnp.concatenate(pages, axis=0)
+
+
+def attend_group(qg, k, v, keep, m_prev, l_prev, acc):
+    """One online-softmax update for one KV head: ``qg`` (R, D) fp32
+    pre-scaled queries sharing that head, ``k``/``v`` (ck, D), ``keep``
+    (R, ck) bool mask, carries ``m_prev``/``l_prev`` (R, 1) and ``acc``
+    (R, D).  Returns the new (m, l, acc).  Plain 2-D matmuls, so the
+    compiled kernel needs no 4-D contraction or sublane reshape."""
+    s = jax.lax.dot_general(qg, k.astype(jnp.float32),
+                            (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)   # (R, ck)
+    s = jnp.where(keep, s, NEG_INF)
+    m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    pv = jnp.dot(p, v.astype(jnp.float32), preferred_element_type=jnp.float32)
+    return m_new, l_prev * alpha + p.sum(-1, keepdims=True), \
+        acc * alpha + pv
 
 
 def decode_attention_op(B: int, S: int, H: int, Hkv: int, D: int,
@@ -49,21 +68,21 @@ def decode_attention_op(B: int, S: int, H: int, Hkv: int, D: int,
 
     Grid: B * (S // ck) steps, batch-major.  `length` (static) masks the
     valid cache prefix; None = full cache.  ``dynamic_length`` instead adds
-    a tiny (B, 1) int32 operand ("len", one row per batch slot, fetched as a
-    (1, 1) block by the batch-major index map) holding each slot's valid
-    prefix, so one compiled kernel serves every decode position of every
-    slot independently — the form the executor binds to a live per-slot
-    ``pos + 1`` vector (continuous batching: slots advance, finish and
-    refill at unrelated cache positions within one launch).
+    a tiny (B, 1) int32 operand ("len", in scalar memory, read at the
+    step's slot) holding each slot's valid prefix, so one compiled kernel
+    serves every decode position of every slot independently — the form
+    the executor binds to a live per-slot ``pos + 1`` vector (continuous
+    batching: slots advance, finish and refill at unrelated cache positions
+    within one launch).
 
     ``block_table=(num_blocks, block_size)`` switches to the paged form:
     k/v become the shared ``(num_blocks, block_size, Hkv, D)`` arena
     (constant index map — the gather is in-body, since fused index maps are
     pure functions of the grid step), ``S`` becomes the per-slot LOGICAL
     capacity (``max_blocks = S // block_size`` table columns), and a
-    ``(B, max_blocks)`` int32 operand ("bt") fetched batch-major maps each
-    slot's logical pages to arena blocks.  Requires ``ck % block_size == 0``
-    so every kv-chunk is a whole number of pages.
+    ``(B, max_blocks)`` int32 operand ("bt", in scalar memory) maps each
+    slot's logical pages to arena blocks.  Requires ``ck % block_size ==
+    0`` so every kv-chunk is a whole number of pages.
     """
     assert S % ck == 0 and H % Hkv == 0
     assert not (dynamic_length and length is not None)
@@ -71,22 +90,23 @@ def decode_attention_op(B: int, S: int, H: int, Hkv: int, D: int,
     rep = H // Hkv
     scale = 1.0 / math.sqrt(D)
     valid_len = S if length is None else int(length)
-    if block_table is not None:
+    paged = block_table is not None
+    if paged:
         num_blocks, bs = block_table
         assert ck % bs == 0 and S % bs == 0
         max_blocks = S // bs
         npc = ck // bs                       # pages per kv-chunk
 
     def body(step, *refs):
-        if block_table is not None:
+        if paged:
             bt_ref, refs = refs[0], refs[1:]
+        b, j = step // nk, step % nk
         if dynamic_length:
             len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref = refs
-            cur_len = len_ref[0, 0]
+            cur_len = len_ref[b, 0]
         else:
             q_ref, k_ref, v_ref, o_ref, m_ref, l_ref = refs
             cur_len = valid_len
-        j = step % nk
 
         @pl.when(j == 0)
         def _():
@@ -94,37 +114,30 @@ def decode_attention_op(B: int, S: int, H: int, Hkv: int, D: int,
             l_ref[...] = jnp.zeros_like(l_ref)
             o_ref[...] = jnp.zeros_like(o_ref)
 
-        q = q_ref[0].astype(jnp.float32) * scale          # (H, D)
-        if block_table is not None:
-            bt = bt_ref[0]                                # (max_blocks,)
-            k = gather_pages(k_ref, bt, j * npc, npc).astype(jnp.float32)
-            v = gather_pages(v_ref, bt, j * npc, npc).astype(jnp.float32)
-        else:
-            k = k_ref[0].astype(jnp.float32)              # (ck, Hkv, D)
-            v = v_ref[0].astype(jnp.float32)
-        qg = q.reshape(Hkv, rep, D)
-        s = jnp.einsum("hrd,khd->hrk", qg, k)             # (Hkv, rep, ck)
-        kpos = j * ck + jax.lax.broadcasted_iota(jnp.int32, (Hkv, rep, ck), 2)
-        s = jnp.where(kpos < cur_len, s, NEG_INF)
-        m_prev = m_ref[0]                                 # (H, 1)
-        m_new = jnp.maximum(m_prev, s.reshape(H, ck).max(-1, keepdims=True))
-        p = jnp.exp(s.reshape(H, ck) - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[0] = l_ref[0] * alpha + p.sum(-1, keepdims=True)
-        pv = jnp.einsum("hrk,khd->hrd", p.reshape(Hkv, rep, ck), v)
-        o_ref[0] = o_ref[0] * alpha + pv.reshape(H, D)
-        m_ref[0] = m_new
+        kpos = j * ck + jax.lax.broadcasted_iota(jnp.int32, (rep, ck), 1)
+        keep = kpos < cur_len
+        for g in range(Hkv):                 # query heads g*rep .. +rep
+            rows = slice(g * rep, (g + 1) * rep)
+            if paged:
+                k = gather_pages(k_ref, bt_ref, b, j * npc, npc, g)
+                v = gather_pages(v_ref, bt_ref, b, j * npc, npc, g)
+            else:
+                k, v = k_ref[0, :, g, :], v_ref[0, :, g, :]     # (ck, D)
+            qg = q_ref[0, rows, :].astype(jnp.float32) * scale  # (rep, D)
+            m, l, acc = attend_group(qg, k, v, keep, m_ref[0, rows, :],
+                                     l_ref[0, rows, :], o_ref[0, rows, :])
+            m_ref[0, rows, :] = m
+            l_ref[0, rows, :] = l
+            o_ref[0, rows, :] = acc
 
         @pl.when(j == nk - 1)
         def _():
             o_ref[0] = o_ref[0] / jnp.maximum(l_ref[0], 1e-30)
 
     itemsize = jnp.dtype(dtype).itemsize
-    len_in = ((Operand((B, 1), jnp.int32, (1, 1), lambda s: (s // nk, 0)),)
-              if dynamic_length else ())
-    if block_table is not None:
-        bt_in = (Operand((B, max_blocks), jnp.int32, (1, max_blocks),
-                         lambda s: (s // nk, 0)),)
+    len_in = (smem_operand((B, 1)),) if dynamic_length else ()
+    if paged:
+        bt_in = (smem_operand((B, max_blocks)),)
         kv = (Operand((num_blocks, bs, Hkv, D), dtype,
                       (num_blocks, bs, Hkv, D), lambda s: (0, 0, 0, 0)),
               Operand((num_blocks, bs, Hkv, D), dtype,
@@ -162,6 +175,8 @@ def decode_attention_op(B: int, S: int, H: int, Hkv: int, D: int,
         + 2.0 * B * H * D * itemsize,
         shrink=shrink,
         tag="framework:decode_attention",
+        # one KV head's fp32 score/probability tiles (rep, ck) live at once
+        extra_vmem_bytes=2 * rep * ck * 4,
         in_names=bt_name + (("len",) if dynamic_length else ())
         + ("q", "k", "v"),
         out_names=("o", "m", "l"))

@@ -19,25 +19,32 @@ from repro.core.op_spec import OpSpec, Operand
 
 def activation_op(R: int, F_in: int, F_out: int, fn: Callable,
                   dtype=jnp.bfloat16, bm: int = 256,
-                  name: str | None = None) -> OpSpec:
+                  name: str | None = None, bn: int | None = None) -> OpSpec:
     """out = fn(h) row-wise; h: (R, F_in) -> out: (R, F_out).
 
     ``fn`` maps a (bm, F_in) block to (bm, F_out) — gated activations
     (silu/gelu-and-multiply) halve F, plain ones keep it.  It must be
     shape-polymorphic in the row dim so the block-shrink variants stay
-    valid.
+    valid.  ``bn`` tiles the output columns (grid over rows x tiles, like
+    kernels/matmul.matmul_1d_op): step j reads input tile j of width
+    ``bn * F_in / F_out`` — for a gated ``fn`` that is the tile-interleaved
+    ``[gate_j | up_j]`` a gated column-tiled matmul writes.
     """
     bm = min(bm, R)
     assert R % bm == 0
+    bn = bn or F_out
+    assert F_out % bn == 0 and (F_in * bn) % F_out == 0
+    nt = F_out // bn
+    blk = lambda s: (s // nt, s % nt)
 
     def body(step, h_ref, o_ref):
         o_ref[...] = fn(h_ref[...]).astype(o_ref.dtype)
 
     itemsize = jnp.dtype(dtype).itemsize
     return OpSpec(
-        name=name or f"act_{R}x{F_in}", grid=R // bm, body=body,
-        inputs=(Operand((R, F_in), dtype, (bm, F_in), lambda s: (s, 0)),),
-        outputs=(Operand((R, F_out), dtype, (bm, F_out), lambda s: (s, 0)),),
+        name=name or f"act_{R}x{F_in}", grid=(R // bm) * nt, body=body,
+        inputs=(Operand((R, F_in), dtype, (bm, F_in // nt), blk),),
+        outputs=(Operand((R, F_out), dtype, (bm, bn), blk),),
         flops=8.0 * R * F_in,
         hbm_bytes=float(R * (F_in + F_out)) * itemsize,
         tag="framework:activation",

@@ -1,7 +1,8 @@
-"""jit'd public wrappers for every kernel: Pallas on TPU, interpret-Pallas or
-the jnp oracle elsewhere (this container is CPU-only; TPU is the target).
+"""jit'd public wrappers for every kernel: compiled Pallas on an
+accelerator, interpret-mode Pallas on the CPU (the test backend).
 
-`use_pallas()` decides per-platform; `force` overrides for tests.
+`_mode()` decides per platform; `force` overrides for tests ("ref" selects
+the jnp oracle).
 """
 from __future__ import annotations
 
@@ -30,7 +31,7 @@ def force(mode: Optional[str]):
 def _mode() -> str:
     if _FORCE:
         return _FORCE
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
+    return "interpret" if jax.default_backend() == "cpu" else "pallas"
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk"))

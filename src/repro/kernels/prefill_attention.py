@@ -12,10 +12,12 @@ attention that shares the launch: N of these chunks (different slots) ⊕ the
 vectorized decode kernel form ONE fused bundle (ServeEngine.decode_graph).
 
 Fusible form mirrors kernels/decode_attention.py: a 1-D grid over kv
-chunks, online-softmax (m, l) carries in small fp32 *outputs* with constant
-index maps (not scratch) so the op composes under core/hfuse.generate.  The
-chunk's start position arrives as a (1, 1) int32 operand ("off"), so one
-compiled kernel serves every chunk of every prompt.
+chunks, per-KV-head 2-D matmuls, the output block as the running
+accumulator and the online-softmax (m, l) statistics in per-op VMEM scratch
+(``OpSpec.scratch``, which core/hfuse.generate allocates per bundle
+member).  The chunk's start position arrives as a (1, 1) int32 operand
+("off", in scalar memory), so one compiled kernel serves every chunk of
+every prompt.
 
 Causal chunk masking against the existing cache: query row r (absolute
 position off + r) admits cache position p iff p <= off + r.  That single
@@ -31,18 +33,20 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.op_spec import MIN_BLOCK_ROWS, OpSpec, Operand
-from repro.kernels.decode_attention import gather_pages
+from repro.core.op_spec import MIN_BLOCK_ROWS, OpSpec, Operand, smem_operand
+from repro.kernels.decode_attention import NEG_INF, attend_group, gather_pages
 
-NEG_INF = -1e30
+LANES = 128
 
 
 def prefill_attention_op(C: int, S: int, H: int, Hkv: int, D: int,
                          dtype=jnp.bfloat16, ck: int = 1024,
                          name: str | None = None,
                          block_table=None) -> OpSpec:
-    """q: (C,H,D) one chunk of one slot; cache k,v: (S,Hkv,D); off: (1,1)
-    int32 absolute start position of the chunk; out o: (C,H,D) fp32.
+    """q: (H,C,D) one chunk of one slot, head-major; cache k,v: (S,Hkv,D);
+    off: (1,1) int32 absolute start position of the chunk; out o: (H,C,D)
+    fp32.  Head-major q/o make every head a leading-axis index, so the
+    kernel loops over a KV head's query heads instead of unrolling them.
 
     Grid: S // ck kv-chunk steps.  The engine scatters the chunk's own k/v
     into rows [off, off+C) before the launch, so the kernel only ever reads
@@ -58,10 +62,10 @@ def prefill_attention_op(C: int, S: int, H: int, Hkv: int, D: int,
     ``block_table=(num_blocks, block_size)``: paged form, mirroring
     kernels/decode_attention.py — k/v are the shared arena, ``S`` is the
     slot's logical capacity, and a ``(1, max_blocks)`` int32 operand ("bt",
-    this slot's table row, constant across the grid like "off") maps
-    logical pages to arena blocks for the in-body gather.  The reassembled
-    ``(ck, Hkv, D)`` block feeds math identical to the contiguous body, so
-    both forms are bitwise-equal on equal logical cache content.
+    this slot's table row, in scalar memory like "off") maps logical pages
+    to arena blocks for the in-body gather.  The reassembled ``(ck, D)``
+    head block feeds math identical to the contiguous body, so both forms
+    are bitwise-equal on equal logical cache content.
     """
     assert S % ck == 0 and H % Hkv == 0
     nk = S // ck
@@ -88,35 +92,30 @@ def prefill_attention_op(C: int, S: int, H: int, Hkv: int, D: int,
             l_ref[...] = jnp.zeros_like(l_ref)
             o_ref[...] = jnp.zeros_like(o_ref)
 
-        off = off_ref[0, 0]
-        q = q_ref[...].astype(jnp.float32) * scale         # (C, H, D)
-        if paged:
-            bt = bt_ref[0]                                 # (max_blocks,)
-            k = gather_pages(k_ref, bt, j * npc, npc).astype(jnp.float32)
-            v = gather_pages(v_ref, bt, j * npc, npc).astype(jnp.float32)
-        else:
-            k = k_ref[...].astype(jnp.float32)             # (ck, Hkv, D)
-            v = v_ref[...].astype(jnp.float32)
-        qg = q.reshape(C, Hkv, rep, D)
-        s = jnp.einsum("chrd,khd->chrk", qg, k)            # (C, Hkv, rep, ck)
-        kpos = j * ck + jax.lax.broadcasted_iota(jnp.int32,
-                                                 (C, Hkv, rep, ck), 3)
-        qpos = off + jax.lax.broadcasted_iota(jnp.int32,
-                                              (C, Hkv, rep, ck), 0)
-        s = jnp.where(kpos <= qpos, s, NEG_INF)
-        sr = s.reshape(C, H, ck)
-        m_prev = m_ref[...]                                # (C, H, 1)
-        m_new = jnp.maximum(m_prev, sr.max(-1, keepdims=True))
-        p = jnp.exp(sr - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + p.sum(-1, keepdims=True)
-        pv = jnp.einsum("chrk,khd->chrd", p.reshape(C, Hkv, rep, ck), v)
-        o_ref[...] = o_ref[...] * alpha + pv.reshape(C, H, D)
-        m_ref[...] = m_new
+        kpos = j * ck + jax.lax.broadcasted_iota(jnp.int32, (C, ck), 1)
+        qpos = off_ref[0, 0] + jax.lax.broadcasted_iota(jnp.int32, (C, ck), 0)
+        keep = kpos <= qpos
+        for g in range(Hkv):
+            if paged:
+                k = gather_pages(k_ref, bt_ref, 0, j * npc, npc, g)
+                v = gather_pages(v_ref, bt_ref, 0, j * npc, npc, g)
+            else:
+                k, v = k_ref[:, g, :], v_ref[:, g, :]        # (ck, D)
+
+            def head(h, carry, k=k, v=v):
+                qh = q_ref[h].astype(jnp.float32) * scale       # (C, D)
+                m, l, acc = attend_group(qh, k, v, keep, m_ref[h][:, :1],
+                                         l_ref[h][:, :1], o_ref[h])
+                m_ref[h] = jnp.broadcast_to(m, (C, LANES))
+                l_ref[h] = jnp.broadcast_to(l, (C, LANES))
+                o_ref[h] = acc
+                return carry
+
+            jax.lax.fori_loop(g * rep, (g + 1) * rep, head, 0)
 
         @pl.when(j == nk - 1)
         def _():
-            o_ref[...] = o_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+            o_ref[...] = o_ref[...] / jnp.maximum(l_ref[...][..., :1], 1e-30)
 
     def shrink(factor: int):
         sck = ck // factor
@@ -127,8 +126,7 @@ def prefill_attention_op(C: int, S: int, H: int, Hkv: int, D: int,
                                     block_table=block_table)
 
     if paged:
-        bt_in = (Operand((1, max_blocks), jnp.int32, (1, max_blocks),
-                         lambda s: (0, 0)),)
+        bt_in = (smem_operand((1, max_blocks)),)
         kv = (Operand((num_blocks, bs, Hkv, D), dtype,
                       (num_blocks, bs, Hkv, D), lambda s: (0, 0, 0, 0)),
               Operand((num_blocks, bs, Hkv, D), dtype,
@@ -144,20 +142,21 @@ def prefill_attention_op(C: int, S: int, H: int, Hkv: int, D: int,
     itemsize = jnp.dtype(dtype).itemsize
     return OpSpec(
         name=resolved, grid=nk, body=body,
-        inputs=(Operand((1, 1), jnp.int32, (1, 1), lambda s: (0, 0)),)
+        inputs=(smem_operand((1, 1)),)
         + bt_in
-        + (Operand((C, H, D), dtype, (C, H, D), lambda s: (0, 0, 0)),)
+        + (Operand((H, C, D), dtype, (H, C, D), lambda s: (0, 0, 0)),)
         + kv,
-        outputs=(Operand((C, H, D), jnp.float32, (C, H, D),
-                         lambda s: (0, 0, 0)),
-                 Operand((C, H, 1), jnp.float32, (C, H, 1),
-                         lambda s: (0, 0, 0)),
-                 Operand((C, H, 1), jnp.float32, (C, H, 1),
-                         lambda s: (0, 0, 0))),
+        outputs=(Operand((H, C, D), jnp.float32, (H, C, D),
+                         lambda s: (0, 0, 0)),),
+        # running max / denominator per (head, row), replicated over the
+        # 128 lanes a (C, 1) column would occupy anyway
+        scratch=(((H, C, LANES), jnp.float32),) * 2,
         flops=2.0 * C * H * S * D * 2,
         hbm_bytes=2.0 * S * Hkv * D * itemsize
         + C * H * D * (itemsize + 4.0) + 4.0 * C * H * 2,
         shrink=shrink,
         tag="framework:prefill_attention",
+        # one head's fp32 score/probability tiles (C, ck) live at once
+        extra_vmem_bytes=2 * C * ck * 4,
         in_names=("off",) + bt_name + ("q", "k", "v"),
-        out_names=("o", "m", "l"))
+        out_names=("o",))

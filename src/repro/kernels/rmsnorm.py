@@ -36,7 +36,11 @@ def rmsnorm(x: jax.Array, scale: jax.Array, *, eps: float = 1e-6,
 
 
 def rmsnorm_op(R: int, d: int, dtype=jnp.bfloat16, bm: int = 256,
-               eps: float = 1e-6) -> OpSpec:
+               eps: float = 1e-6, repeat: int = 1) -> OpSpec:
+    """Fusible form.  ``repeat`` re-emits each row block on that many
+    consecutive steps (the block index holds, so nothing is re-fetched):
+    the grid then matches a column-tiled consumer's, which is what lets
+    norm -> matmul stitch when the matmul tiles its weight."""
     assert R % bm == 0
 
     def body(step, x_ref, s_ref, o_ref):
@@ -44,10 +48,11 @@ def rmsnorm_op(R: int, d: int, dtype=jnp.bfloat16, bm: int = 256,
 
     itemsize = jnp.dtype(dtype).itemsize
     return OpSpec(
-        name=f"rmsnorm_{R}x{d}", grid=R // bm, body=body,
-        inputs=(Operand((R, d), dtype, (bm, d), lambda s: (s, 0)),
+        name=f"rmsnorm_{R}x{d}", grid=(R // bm) * repeat, body=body,
+        inputs=(Operand((R, d), dtype, (bm, d), lambda s: (s // repeat, 0)),
                 Operand((1, d), jnp.float32, (1, d), lambda s: (0, 0))),
-        outputs=(Operand((R, d), dtype, (bm, d), lambda s: (s, 0)),),
+        outputs=(Operand((R, d), dtype, (bm, d),
+                         lambda s: (s // repeat, 0)),),
         flops=4.0 * R * d,
         hbm_bytes=2.0 * R * d * itemsize,
         tag="framework:rmsnorm",

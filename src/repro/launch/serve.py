@@ -53,7 +53,7 @@ def build_requests(cfg, args) -> list[Request]:
     return reqs
 
 
-def main(argv=None):
+def make_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--scale", choices=["smoke", "full"], default="smoke")
@@ -145,7 +145,10 @@ def main(argv=None):
                     default=None,
                     help="pick planned schedules by measurement "
                          "(core/timing.make_measure backend)")
-    args = ap.parse_args(argv)
+    return ap
+
+
+def check_args(ap: argparse.ArgumentParser, args) -> None:
     if args.measure and not args.plan_fusion:
         ap.error("--measure only applies to --plan-fusion schedule selection")
     if args.kv_block_size > 0 and not args.plan_fusion:
@@ -162,23 +165,37 @@ def main(argv=None):
     if args.expect_sharded_parity and args.mesh_shape <= 1:
         ap.error("--expect-sharded-parity requires --mesh-shape > 1")
 
+
+def load_model(args):
+    """(cfg, params): the config at ``--scale``, weights drawn from
+    ``--seed``."""
     cfg = get_config(args.arch)
     if args.scale == "smoke":
         cfg = cfg.reduced()
-    params = lm.init(cfg, jax.random.PRNGKey(0))
-    mesh = None
-    if args.mesh_shape > 1:
-        from jax.sharding import Mesh
-        devs = jax.devices()
-        if len(devs) < args.mesh_shape:
-            raise SystemExit(
-                f"[sharded] FAIL: --mesh-shape {args.mesh_shape} needs that "
-                f"many local devices, found {len(devs)} (on CPU set "
-                "XLA_FLAGS=--xla_force_host_platform_device_count="
-                f"{args.mesh_shape})")
-        mesh = Mesh(np.array(devs)[:args.mesh_shape], (args.shard_axis,))
-        print(f"[sharded] {args.mesh_shape}-way tensor-parallel serve over "
-              f"mesh axis {args.shard_axis!r}")
+    # one jitted program draws every leaf on the device (the same values as
+    # the eager lm.init, without a dispatch per leaf)
+    init = jax.jit(lambda key: lm.init(cfg, key))
+    return cfg, init(jax.random.PRNGKey(args.seed))
+
+
+def build_mesh(args):
+    """The 1-D tensor-parallel mesh of ``--mesh-shape`` local devices, or
+    None."""
+    if args.mesh_shape <= 1:
+        return None
+    from jax.sharding import Mesh
+    devs = jax.devices()
+    if len(devs) < args.mesh_shape:
+        raise SystemExit(
+            f"[sharded] FAIL: --mesh-shape {args.mesh_shape} needs that "
+            f"many local devices, found {len(devs)} (on CPU set "
+            "XLA_FLAGS=--xla_force_host_platform_device_count="
+            f"{args.mesh_shape})")
+    return Mesh(np.array(devs)[:args.mesh_shape], (args.shard_axis,))
+
+
+def build_engine(args, cfg, params, mesh=None) -> ServeEngine:
+    """The serve engine exactly as the launcher configures it."""
     measure = None
     schedule_cache = None
     if args.plan_fusion:
@@ -189,19 +206,33 @@ def main(argv=None):
     budget = PrefillBudget(chunk_rows=args.chunk_rows,
                            max_coresident_chunks=args.coresident_chunks,
                            policy=args.prefill_policy)
-    engine = ServeEngine(cfg, params, batch=args.batch,
-                         max_len=args.prompt_len + args.shared_prefix
-                         + args.stagger + args.max_new + 8,
-                         plan_fusion=args.plan_fusion, measure=measure,
-                         schedule_cache=schedule_cache,
-                         scheduling=args.scheduling,
-                         prefill_budget=budget,
-                         reject_overlong=args.reject_overlong,
-                         paged_kv=args.kv_block_size > 0,
-                         kv_block_size=args.kv_block_size or 16,
-                         kv_blocks=args.kv_blocks,
-                         kv_slot_blocks=args.kv_slot_blocks,
-                         mesh=mesh, shard_axis=args.shard_axis)
+    return ServeEngine(cfg, params, batch=args.batch,
+                       max_len=args.prompt_len + args.shared_prefix
+                       + args.stagger + args.max_new + 8,
+                       plan_fusion=args.plan_fusion, measure=measure,
+                       schedule_cache=schedule_cache,
+                       scheduling=args.scheduling,
+                       prefill_budget=budget,
+                       reject_overlong=args.reject_overlong,
+                       paged_kv=args.kv_block_size > 0,
+                       kv_block_size=args.kv_block_size or 16,
+                       kv_blocks=args.kv_blocks,
+                       kv_slot_blocks=args.kv_slot_blocks,
+                       mesh=mesh, shard_axis=args.shard_axis)
+
+
+def main(argv=None):
+    from repro import compile_cache
+    compile_cache.enable()
+    ap = make_parser()
+    args = ap.parse_args(argv)
+    check_args(ap, args)
+    cfg, params = load_model(args)
+    mesh = build_mesh(args)
+    if mesh is not None:
+        print(f"[sharded] {args.mesh_shape}-way tensor-parallel serve over "
+              f"mesh axis {args.shard_axis!r}")
+    engine = build_engine(args, cfg, params, mesh)
     if engine.fusion_plan is not None:
         print("[plan-fusion] decode-step bundles:")
         for row in engine.fusion_plan.summary():
@@ -249,13 +280,7 @@ def main(argv=None):
           f"in {dt:.2f}s ({total_new / dt:.1f} tok/s)")
     if args.expect_sharded_parity:
         # same deterministic trace on one device; every stream must match
-        ref_engine = ServeEngine(
-            cfg, params, batch=args.batch,
-            max_len=args.prompt_len + args.shared_prefix + args.stagger
-            + args.max_new + 8,
-            plan_fusion=args.plan_fusion, schedule_cache=schedule_cache,
-            scheduling=args.scheduling, prefill_budget=budget,
-            reject_overlong=args.reject_overlong)
+        ref_engine = build_engine(args, cfg, params)
         ref = build_requests(cfg, args)
         ref_engine.run(ref)
         bad = [r.rid for r, s in zip(ref, reqs)

@@ -39,6 +39,8 @@ def build(cfg, tcfg: TrainConfig, mesh=None, update_program=None):
 
 
 def main(argv=None):
+    from repro import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--scale", choices=["smoke", "full"], default="smoke")

@@ -447,10 +447,23 @@ def loss_fn(cfg: ModelConfig, params, batch, *, remat: bool = True):
     return total, {"ce": loss, "aux": aux}
 
 
-def prefill(cfg: ModelConfig, params, batch, max_len: int):
-    """-> (cache, last_token_logits)."""
+def prefill(cfg: ModelConfig, params, batch, max_len: int,
+            compute_dtype=None):
+    """-> (cache, last_token_logits).
+
+    ``compute_dtype`` (e.g. float32) runs the whole pass at that precision:
+    activations start there and each layer's weights are cast inside the
+    layer scan, so a full-precision reference needs no second copy of the
+    weights in device memory.  Pass a ``cfg`` whose ``dtype`` matches."""
+    def cast(tree):
+        if compute_dtype is None:
+            return tree
+        return jax.tree.map(
+            lambda a: a.astype(compute_dtype)
+            if jnp.issubdtype(a.dtype, jnp.floating) else a, tree)
+
     x, _mask = _embed_inputs(cfg, params, batch)
-    x = shard(x, ("batch", "seq", "embed"))
+    x = shard(cast(x), ("batch", "seq", "embed"))
     S = x.shape[1]
     cache: dict = {"pos": jnp.asarray(S, jnp.int32)}
     for run in layer_runs(cfg):
@@ -458,7 +471,7 @@ def prefill(cfg: ModelConfig, params, batch, max_len: int):
 
         def body(carry, p_slice, _run=run):
             xx = carry
-            y, _a, c = block_apply_seq(cfg, _run, p_slice, xx,
+            y, _a, c = block_apply_seq(cfg, _run, cast(p_slice), xx,
                                        want_cache=True, max_len=max_len)
             return y, c
 
@@ -467,8 +480,10 @@ def prefill(cfg: ModelConfig, params, batch, max_len: int):
         else:
             x, run_cache = body(x, p_run)
         cache[run.name] = run_cache
-    x = layers.apply_norm(cfg, params["final_norm"], x[:, -1:])
-    return cache, _head(cfg, params, x)[:, 0]
+    head_params = cast({k: params[k] for k in ("embed", "head", "final_norm")
+                        if k in params})
+    x = layers.apply_norm(cfg, head_params["final_norm"], x[:, -1:])
+    return cache, _head(cfg, head_params, x)[:, 0]
 
 
 def greedy_sample(cfg: ModelConfig, logits):

@@ -283,7 +283,19 @@ def pad_prefill_rows(rows: int) -> int:
     return PrefillBudget().pad_rows(rows)
 
 
-def _mlp_from_h(cfg: ModelConfig, h, w_out):
+def _out_proj(x, w, axis: Optional[str] = None):
+    """``x @ w``.  Under tensor parallelism (``axis`` set) ``w`` is this
+    shard's row slab: the shards' fp32 partial products sum across ``axis``
+    and round to ``x.dtype`` once, as the single-device matmul does, so the
+    sharded step reproduces the one-device numerics up to fp32 summation
+    order."""
+    if axis is None:
+        return x @ w
+    return jax.lax.psum(jnp.dot(x, w, preferred_element_type=jnp.float32),
+                        axis).astype(x.dtype)
+
+
+def _mlp_from_h(cfg: ModelConfig, h, w_out, axis: Optional[str] = None):
     """layers.mlp, minus the in-projection the executor already ran."""
     act = cfg.activation
     if act in ("silu", "gelu"):
@@ -296,7 +308,7 @@ def _mlp_from_h(cfg: ModelConfig, h, w_out):
         h = jnp.square(jax.nn.relu(h))
     else:
         raise ValueError(act)
-    return h @ w_out
+    return _out_proj(h, w_out, axis)
 
 
 class ServeEngine:
@@ -321,7 +333,7 @@ class ServeEngine:
         self.scheduling = scheduling
         # tensor-parallel serve: with a mesh whose ``shard_axis`` has
         # extent n > 1, the executed continuous step runs under
-        # compat.shard_map — each shard owns num_heads/n query heads,
+        # jax.shard_map — each shard owns num_heads/n query heads,
         # num_kv_heads/n KV-cache heads and d_ff/n FFN columns, plans its
         # own shard-local fusion, and psums the two row-sharded output
         # projections.  The slot manager, the per-slot (B,) position
@@ -436,7 +448,11 @@ class ServeEngine:
         # each shard a self-consistent [q_s|k_s|v_s] / [gate_s|up_s] slab)
         self._step_params = params
         if self.tp_shards > 1:
-            self._step_params = self._tp_permuted_params()
+            # placed on the mesh once: every step then hands shard_map
+            # operands that already live where its in_specs say
+            permuted = self._tp_permuted_params()
+            self._step_params = self._tp_place(
+                permuted, self._tp_param_specs(permuted))
         self.fusion_plan = None
         if plan_fusion:
             reason = executable_decode_supported(cfg)
@@ -483,6 +499,42 @@ class ServeEngine:
             return self._aligned_len()
         return self.max_len
 
+    @staticmethod
+    def _kv_chunk(S: int) -> int:
+        """KV rows per attention grid step: the largest 128-multiple <= 512
+        dividing the (128-aligned) cache length.  Paged caches hold whole
+        pages per chunk because the block size divides 128."""
+        return next(c for c in range(min(512, S), 0, -128) if S % c == 0)
+
+    def chunk_rows(self, budget: Optional[PrefillBudget] = None) -> int:
+        """Prompt rows per prefill chunk: the budget's ``effective_chunk``
+        against the cache, shrunk until the co-resident chunks' attention
+        kernels take at most half the VMEM budget double-buffered (the
+        decode side's members share the launch).  At smoke widths the
+        budget's chunk always fits; at published widths this is what keeps
+        a 2048-row request for a chunk from asking for gigabytes of VMEM."""
+        from repro.core.cost_model import VMEM_BUDGET
+        from repro.kernels.prefill_attention import prefill_attention_op
+
+        budget = budget or self.prefill_budget
+        cfg = self.cfg
+        paged = getattr(self, "paged_kv", False)
+        S = self.cache_len if paged else self._aligned_len()
+        multiple = self.kv_block_size if paged else 1
+        tp = getattr(self, "tp_shards", 1)
+        rows = budget.chunk_rows
+        while True:
+            C = dataclasses.replace(budget, chunk_rows=rows).effective_chunk(
+                S, multiple=multiple)
+            op = prefill_attention_op(
+                C, S, cfg.num_heads // tp, cfg.num_kv_heads // tp,
+                cfg.resolved_head_dim, dtype=jnp.dtype(cfg.dtype),
+                ck=self._kv_chunk(S))
+            need = 2 * op.vmem_bytes * budget.max_coresident_chunks
+            if C <= multiple or need <= VMEM_BUDGET // 2:
+                return C
+            rows = C - 1
+
     def decode_graph(self, *, budget: Optional[PrefillBudget] = None,
                      prefill_chunks: int = 0, ffn_rows: int = 0,
                      dynamic_length: bool = True,
@@ -514,7 +566,7 @@ class ServeEngine:
         from repro.core import planner
         from repro.kernels import elementwise
         from repro.kernels.decode_attention import decode_attention_op
-        from repro.kernels.matmul import matmul_1d_op
+        from repro.kernels.matmul import matmul_1d_op, weight_tile
         from repro.kernels.prefill_attention import prefill_attention_op
         from repro.kernels.rmsnorm import rmsnorm_op
 
@@ -544,14 +596,9 @@ class ServeEngine:
         bt = (self.kv_blocks, self.kv_block_size) if paged else None
         B = self.batch
 
-        norm1 = dataclasses.replace(rmsnorm_op(R=B, d=d, dtype=dt, bm=B),
-                                    name="decode_norm1")
         norm2 = dataclasses.replace(rmsnorm_op(R=B, d=d, dtype=dt, bm=B),
                                     name="decode_norm2")
-        # largest 128-multiple chunk <= 1024 that divides S (S is 128-aligned,
-        # so the scan bottoms out at ck=128; kv_block_size divides 128, so a
-        # paged kv-chunk is always a whole number of pages)
-        ck = next(c for c in range(min(1024, S), 0, -128) if S % c == 0)
+        ck = self._kv_chunk(S)
         att = decode_attention_op(B=B, S=S, H=H, Hkv=Hkv, D=D, dtype=dt,
                                   ck=ck, dynamic_length=dynamic_length,
                                   block_table=bt)
@@ -559,9 +606,26 @@ class ServeEngine:
         # FFN in-projection — weight streaming dominates at serving batch
         # (memory-bound; the honest fig_framework finding), so the planner
         # pairs it with the prefill chunk's genuinely compute-bound matmul.
-        proj = matmul_1d_op(M=B, K=d, N=ffn_in, dtype=dt, bm=B)
+        # Weights are streamed in column tiles of a few MiB (weight_tile):
+        # a whole (d, 2 d_ff) weight would not fit VMEM.
+        gated = (cfg.moe is None and cfg.d_ff > 0
+                 and cfg.activation in ("silu", "gelu"))
+        ffn_bn = weight_tile(d, ffn_out if gated else ffn_in, dt,
+                             views=2 if gated else 1)
+        proj = matmul_1d_op(M=B, K=d, N=ffn_in, dtype=dt, bm=B, bn=ffn_bn,
+                            gated=gated)
         proj = dataclasses.replace(
             proj, name="moe_router" if cfg.moe is not None else "ffn_proj")
+        qkv_n = (H + 2 * Hkv) * D
+        qkv = dataclasses.replace(
+            matmul_1d_op(M=B, K=d, N=qkv_n, dtype=dt, bm=B,
+                         bn=weight_tile(d, qkv_n, dt)),
+            name="qkv_proj")
+        # the pre-attention norm repeats its row block once per QKV column
+        # tile, so the norm -> QKV pair stitches (equal grids, same block)
+        norm1 = dataclasses.replace(
+            rmsnorm_op(R=B, d=d, dtype=dt, bm=B, repeat=qkv.grid),
+            name="decode_norm1")
         executable = executable_decode_supported(cfg) is None
         if executable and cfg.moe is not None:
             # Executed MoE decode: the router projection and the grouped
@@ -575,9 +639,6 @@ class ServeEngine:
             from repro.kernels.moe_gmm import moe_gmm_op
             from repro.models import moe as moe_mod
             m = cfg.moe
-            qkv = dataclasses.replace(
-                matmul_1d_op(M=B, K=d, N=(H + 2 * Hkv) * D, dtype=dt, bm=B),
-                name="qkv_proj")
             proj = dataclasses.replace(
                 matmul_1d_op(M=B, K=d, N=m.num_experts,
                              dtype=jnp.float32, bm=B),
@@ -606,16 +667,13 @@ class ServeEngine:
             # producer→consumer pair can stitch into one launch.  Stitched or
             # not, the op set and numerics are identical — only the epilogue
             # declarations below differ.
-            qkv = dataclasses.replace(
-                matmul_1d_op(M=B, K=d, N=(H + 2 * Hkv) * D, dtype=dt, bm=B),
-                name="qkv_proj")
             act_fn = {"silu": elementwise.silu_gate,
                       "gelu": elementwise.gelu_gate,
                       "gelu_mlp": elementwise.gelu_plain,
                       "relu2_mlp": elementwise.relu2}[cfg.activation]
             act = elementwise.activation_op(
                 R=B, F_in=ffn_in, F_out=ffn_out, fn=act_fn,
-                dtype=dt, bm=B, name="decode_act")
+                dtype=dt, bm=B, name="decode_act", bn=ffn_bn)
             if getattr(self, "stitch_epilogues", True):
                 norm1 = dataclasses.replace(norm1,
                                             epilogue=(qkv.name, "x"))
@@ -653,8 +711,7 @@ class ServeEngine:
             pf = dataclasses.replace(pf, name="prefill_ffn")
             graph.append(planner.GraphOp(pf))
         if prefill_chunks:
-            C = budget.effective_chunk(
-                S, multiple=self.kv_block_size if paged else 1)
+            C = self.chunk_rows(budget)
             sfx = f"_pg{self.kv_block_size}" if paged else ""
             for i in range(prefill_chunks):
                 pa = prefill_attention_op(
@@ -726,7 +783,10 @@ class ServeEngine:
             ffn_rows = prefill_rows
         cfg = self.cfg
         if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+            # the Pallas interpreter is the CPU test backend only: any
+            # accelerator compiles the kernels, and a kernel the compiler
+            # refuses fails the step instead of silently interpreting
+            interpret = jax.default_backend() == "cpu"
         d, H, Hkv = cfg.d_model, cfg.num_heads, cfg.num_kv_heads
         D = cfg.resolved_head_dim
         # tensor-parallel: the program is traced once and runs SPMD inside
@@ -789,18 +849,17 @@ class ServeEngine:
             state["v_cache"] = state["v_cache"].at[rows, cols].set(v_row)
             return state
 
+        psum_axis = axis if tp > 1 else None   # row-sharded W_o / W_out
+
         def att_put(state, o):
-            attn_out = o.astype(dt).reshape(B, H * D) @ state["w_o"]
-            if tp > 1:          # row-sharded W_o: sum the partial products
-                attn_out = jax.lax.psum(attn_out, axis)
+            attn_out = _out_proj(o.astype(dt).reshape(B, H * D),
+                                 state["w_o"], psum_axis)
             state = dict(state)
             state["h_mid"] = state["x"] + attn_out              # residual 1
             return state
 
         def act_put(state, h_act):
-            ff = h_act.astype(dt) @ state["w_out"]
-            if tp > 1:          # row-sharded W_out: sum the partial products
-                ff = jax.lax.psum(ff, axis)
+            ff = _out_proj(h_act.astype(dt), state["w_out"], psum_axis)
             state = dict(state)
             state["x_out"] = state["h_mid"] + ff                # residual 2
             return state
@@ -897,11 +956,13 @@ class ServeEngine:
         else:
             proj_name = "moe_router" if cfg.moe is not None else "ffn_proj"
             chain2 = stitch.chain_label(proj_name, "decode_act")
+            # a gated projection reads the [gate | up] weight twice, as its
+            # gate and up column-tile views
             if chain2 in plan_names:
-                reg.bind(chain2, x="h2", w="w_in",
+                reg.bind(chain2, x="h2", w="w_in", w_up="w_in",
                          outputs={"out": Slot(put=act_put)})
             else:
-                reg.bind(proj_name, x="h2", w="w_in",
+                reg.bind(proj_name, x="h2", w="w_in", w_up="w_in",
                          outputs={"out": "h_ffn"})
                 reg.bind("decode_act", h="h_ffn",
                          outputs={"out": Slot(put=act_put)})
@@ -926,9 +987,7 @@ class ServeEngine:
                                    s["k_cache"][s[f"pf{i}_slot"]]),
                          "v": Slot(get=lambda s, i=i:
                                    s["v_cache"][s[f"pf{i}_slot"]])}
-            reg.bind(g.op.name, inputs=pf_in,
-                     outputs={"o": f"pf{i}_o", "m": f"pf{i}_m",
-                              "l": f"pf{i}_l"})
+            reg.bind(g.op.name, inputs=pf_in, outputs={"o": f"pf{i}_o"})
         return executor.compile_plan(plan, bindings=reg, interpret=interpret)
 
     def _layer_state(self, p, kv, x, pos, act):
@@ -994,25 +1053,42 @@ class ServeEngine:
         p[run.name] = blk
         return p
 
-    def _tp_specs(self, n_chunks: int):
-        """(in_specs, out_specs) for shard_map around the continuous step:
-        weight and KV-cache leaves shard by name (sharding.tp_param_pspec /
-        tp_cache_pspec), everything the slot manager owns — tokens, masks,
-        positions, chunk metadata, block tables — replicates."""
-        from jax.sharding import PartitionSpec as P
+    def _tp_param_specs(self, params):
+        """PartitionSpec tree for the step params: weight leaves shard by
+        name (sharding.tp_param_pspec), everything else replicates."""
         from jax.tree_util import tree_map_with_path
         from repro.distributed import sharding as shd
-        axis = self.shard_axis
-
-        p_specs = tree_map_with_path(
+        return tree_map_with_path(
             lambda path, leaf: shd.tp_param_pspec(path[-1].key,
-                                                  jnp.ndim(leaf), axis),
-            self._step_params)
-        c_specs = tree_map_with_path(
+                                                  jnp.ndim(leaf),
+                                                  self.shard_axis),
+            params)
+
+    def _tp_cache_specs(self):
+        """PartitionSpec tree for the slot cache (sharding.tp_cache_pspec):
+        k/v shard their head axis, positions replicate."""
+        from jax.tree_util import tree_map_with_path
+        from repro.distributed import sharding as shd
+        return tree_map_with_path(
             lambda path, leaf: shd.tp_cache_pspec(path[-1].key,
-                                                  jnp.ndim(leaf), axis),
-            jax.eval_shape(self._init_slot_cache))
-        in_specs = (p_specs, c_specs, P(), P())
+                                                  jnp.ndim(leaf),
+                                                  self.shard_axis),
+            jax.eval_shape(self._init_slot_cache_local))
+
+    def _tp_place(self, tree, specs):
+        """Put ``tree`` on the mesh once, leaf by leaf, with ``specs``."""
+        from jax.sharding import NamedSharding
+        return jax.device_put(
+            tree, jax.tree.map(lambda s: NamedSharding(self.mesh, s), specs))
+
+    def _tp_specs(self, n_chunks: int):
+        """(in_specs, out_specs) for shard_map around the continuous step:
+        weight and KV-cache leaves shard by name, everything the slot
+        manager owns — tokens, masks, positions, chunk metadata, block
+        tables — replicates."""
+        from jax.sharding import PartitionSpec as P
+        c_specs = self._tp_cache_specs()
+        in_specs = (self._tp_param_specs(self._step_params), c_specs, P(), P())
         if getattr(self, "paged_kv", False):
             in_specs += (P(),)
         if n_chunks:
@@ -1122,6 +1198,13 @@ class ServeEngine:
     # Continuous batching: per-slot cache positions, admit/refill per token
     # ------------------------------------------------------------------
     def _init_slot_cache(self):
+        """The slot cache, on the mesh under tensor parallelism."""
+        cache = self._init_slot_cache_local()
+        if getattr(self, "tp_shards", 1) > 1:
+            cache = self._tp_place(cache, self._tp_cache_specs())
+        return cache
+
+    def _init_slot_cache_local(self):
         """The slot cache: ``lm.init_cache`` with the scalar wave position
         replaced by the per-slot position vector (B,).  Paged: the k/v
         leaves are the flat ``(kv_blocks, block_size, Hkv, D)`` arena the
@@ -1229,7 +1312,7 @@ class ServeEngine:
         program runs once per layer inside ``lax.scan``, carrying the
         decode hidden (B, d) and each chunk's (C, d) hidden between
         layers.  Under tensor parallelism the whole step body runs inside
-        ``compat.shard_map``: every shard executes its own shard-local
+        ``jax.shard_map``: every shard executes its own shard-local
         fused program, the output projections psum, and logits/positions
         come out replicated."""
         from repro.models import layers
@@ -1243,14 +1326,13 @@ class ServeEngine:
         n = n_chunks
         tp = self.tp_shards
         axis = self.shard_axis
+        psum_axis = axis if tp > 1 else None   # row-sharded W_o / W_out
         H_l = cfg.num_heads // tp
         Hkv_l = cfg.num_kv_heads // tp
         D = cfg.resolved_head_dim
         paged = getattr(self, "paged_kv", False)
         bs = self.kv_block_size if paged else 0
-        C = self.prefill_budget.effective_chunk(
-            self.cache_len if paged else self._aligned_len(),
-            multiple=bs if paged else 1)
+        C = self.chunk_rows()
         program = self.build_decode_program(prefill_chunks=n)
         # a chunk counts as fused when it shares a launch with any
         # decode-side member — decode attention OR the stitched FFN chain
@@ -1266,6 +1348,7 @@ class ServeEngine:
             "total_launches": len(program.steps),
             "fused_members": [sorted(ms) for ms in program.fused_members],
             "steps": program.describe(),
+            "interpret": program.interpret,
         }
         is_moe = cfg.moe is not None
 
@@ -1318,7 +1401,7 @@ class ServeEngine:
                     vc = jax.lax.dynamic_update_slice(
                         vc, vp.astype(vc.dtype),
                         (ch_slots[i], ch_offs[i], 0, 0))
-                state[f"pf{i}_q"] = qp[0].astype(dt)
+                state[f"pf{i}_q"] = qp[0].transpose(1, 0, 2).astype(dt)
                 state[f"pf{i}_slot"] = ch_slots[i]
                 state[f"pf{i}_off"] = jnp.reshape(ch_offs[i],
                                                   (1, 1)).astype(jnp.int32)
@@ -1331,10 +1414,9 @@ class ServeEngine:
             # Under TP both output projections are row-sharded partials.
             new_chs = []
             for i in range(n):
-                o = state[f"pf{i}_o"].astype(dt)             # (C, H_l, D)
-                attn_out = o.reshape(C, -1) @ p["attn"]["w_o"]
-                if tp > 1:
-                    attn_out = jax.lax.psum(attn_out, axis)
+                o = state[f"pf{i}_o"].astype(dt)             # (H_l, C, D)
+                attn_out = _out_proj(o.transpose(1, 0, 2).reshape(C, -1),
+                                     p["attn"]["w_o"], psum_axis)
                 xm = chs[i] + attn_out
                 h2 = layers.apply_norm(cfg, p["norm2"], xm[None])
                 if is_moe:
@@ -1345,9 +1427,7 @@ class ServeEngine:
                     ff = lm._apply_ffn(cfg, p, h2, True)[0][0]
                 else:
                     ff = _mlp_from_h(cfg, h2[0] @ p["mlp"]["w_in"],
-                                     p["mlp"]["w_out"])
-                if tp > 1:
-                    ff = jax.lax.psum(ff, axis)
+                                     p["mlp"]["w_out"], psum_axis)
                 new_chs.append(xm + ff)
             ret = (state["x_out"],
                    {"k": state["k_cache"], "v": state["v_cache"]},
@@ -1427,16 +1507,15 @@ class ServeEngine:
             return (logits, new_cache, jnp.stack(pf_logits)) + moe_tail
 
         if tp > 1:
-            from repro.distributed.compat import shard_map
             in_specs, out_specs = self._tp_specs(n)
             # fully-manual SPMD: every shard traces the same program over
             # its slab; logits come out replicated (both projections psum
             # before anything data-dependent), so sampling stays host-side
-            # and shard-invariant.  check_vma=False: the 0.4.x fallback
-            # cannot prove replication through the Pallas calls.
-            core = shard_map(core, mesh=self.mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=(axis,),
-                             check_vma=False)
+            # and shard-invariant.  check_vma=False: replication is not
+            # inferred through the Pallas calls.
+            core = jax.shard_map(core, mesh=self.mesh, in_specs=in_specs,
+                                 out_specs=out_specs, axis_names={axis},
+                                 check_vma=False)
 
         def step(params, cache, tokens, active, bt=None,
                  ch_slots=None, ch_offs=None, ch_valid=None, ch_tokens=None):
@@ -1537,9 +1616,7 @@ class ServeEngine:
         hand-wired fallback prefills whole prompts alongside the decode
         (``_run_continuous_plain``)."""
         paged = getattr(self, "paged_kv", False)
-        chunk = self.prefill_budget.effective_chunk(
-            self.cache_len if paged else self._aligned_len(),
-            multiple=self.kv_block_size if paged else 1)
+        chunk = self.chunk_rows()
         for r in requests:
             if len(r.prompt) > self.cache_len:
                 raise ValueError(
@@ -1577,9 +1654,7 @@ class ServeEngine:
         pool = self.kv_pool
         paged = pool is not None
         is_moe = self.cfg.moe is not None
-        C = budget.effective_chunk(
-            self.cache_len if paged else self._aligned_len(),
-            multiple=self.kv_block_size if paged else 1)
+        C = self.chunk_rows()
         if paged:
             # the pool persists across runs (prefix cache survives); this
             # run's stats report the deltas

@@ -9,8 +9,8 @@ Two update paths:
                              memory-bound per-tensor update "kernels" become
                              one launch over a concatenated flat buffer —
                              the paper's fusion applied to the optimizer
-                             (DESIGN.md §4.3).  TPU-only; falls back to the
-                             jnp path off-TPU.
+                             (DESIGN.md §4.3).  Compiled on an accelerator,
+                             interpreted on the CPU test backend.
 
 Gradient compression (int8 + error feedback) lives in
 repro/distributed/compression.py and wraps the gradient *before* the update.
@@ -98,7 +98,7 @@ def update(ocfg: AdamWConfig, grads, state: OptState, params, *,
                                            lr=lr, bc1=bc1, bc2=bc2)
         return new_params, OptState(new_m, new_v, cnt)
 
-    if ocfg.hfused and jax.default_backend() == "tpu":
+    if ocfg.hfused:                 # compiled Pallas; interpreted on CPU
         from repro.kernels import ops as kops
         new_params, new_m, new_v = kops.hfused_adamw(
             params, grads, state.m, state.v,

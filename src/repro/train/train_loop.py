@@ -212,7 +212,7 @@ def build_update_program(params, ocfg: Optional[AdamWConfig] = None, *,
 
     ocfg = ocfg or AdamWConfig()
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = jax.default_backend() == "cpu"
     graph, layout = update_graph(
         params, bm=bm, max_tensors=None, include_dW=False,
         b1=ocfg.b1, b2=ocfg.b2, eps=ocfg.eps, wd=ocfg.weight_decay)

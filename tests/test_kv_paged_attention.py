@@ -88,7 +88,7 @@ def test_paged_prefill_bitwise_equals_contiguous(C, nck, shrink, seed):
     num_blocks = S // BS + 2
     key = jax.random.PRNGKey(seed)
     kq, kkv = jax.random.split(key)
-    q = jax.random.normal(kq, (C, H, D), jnp.float32)
+    q = jax.random.normal(kq, (H, C, D), jnp.float32)         # head-major
     kc, vc, ka, va, bt = _paged_cache(kkv, 1, S, num_blocks, seed)
     off = jnp.full((1, 1),
                    int(np.random.default_rng(seed + 1).integers(0, S - C + 1)),

@@ -176,8 +176,10 @@ def test_prefill_kernel_matches_reference(C, S, Hkv, ck, off):
     v = jnp.asarray(rng.normal(size=(S, Hkv, D)), jnp.float32)
     op = prefill_attention_op(C, S, H, Hkv, D, dtype=jnp.float32, ck=ck)
     offa = jnp.full((1, 1), off, jnp.int32)
-    o, _m, _l = hfuse.run_single(op, interpret=True)(offa, q, k, v)
-    np.testing.assert_allclose(np.asarray(o), np.asarray(_ref_attn(q, k, v, off)),
+    (o,) = hfuse.run_single(op, interpret=True)(
+        offa, q.transpose(1, 0, 2), k, v)                  # head-major q/o
+    np.testing.assert_allclose(np.asarray(o.transpose(1, 0, 2)),
+                               np.asarray(_ref_attn(q, k, v, off)),
                                atol=1e-4, rtol=1e-4)
 
 
@@ -186,7 +188,7 @@ def test_prefill_op_shrinks_blockwise():
     small = op.shrink(2)
     assert small is not None and small.grid == 4      # ck 64 -> 32
     rng = np.random.default_rng(3)
-    q = jnp.asarray(rng.normal(size=(8, 4, 16)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(4, 8, 16)), jnp.float32)   # (H, C, D)
     k = jnp.asarray(rng.normal(size=(128, 4, 16)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(128, 4, 16)), jnp.float32)
     offa = jnp.full((1, 1), 16, jnp.int32)

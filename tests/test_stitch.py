@@ -124,6 +124,31 @@ def test_matmul_activation_chain_parity():
     assert np.array_equal(np.asarray(o_chain), np.asarray(o_sep))
 
 
+@pytest.mark.parametrize("bn", [None, 128, 256])
+def test_gated_tiled_matmul_activation_matches_reference(bn):
+    """A gated in-projection tiled over its columns (the [gate | up] weight
+    read as two tile views) stitched onto the column-tiled activation: the
+    chain equals the separate pair bitwise and silu(x@gate) * (x@up)."""
+    R, K, F, bm = 16, 64, 512, 8
+    mm = matmul_1d_op(M=R, K=K, N=2 * F, dtype=jnp.float32, bm=bm, bn=bn,
+                      gated=True)
+    act = activation_op(R, 2 * F, F, silu_gate, dtype=jnp.float32, bm=bm,
+                        bn=bn)
+    assert mm.grid == act.grid == (R // bm) * (F // (bn or F))
+    chain = stitch(mm, act, "h")
+    rng = np.random.default_rng(11)
+    x = jnp.asarray(rng.normal(size=(R, K)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(K, 2 * F)) / 8, jnp.float32)
+    (h,) = _run(mm, x, w, w)
+    (o_sep,) = _run(act, h)
+    (o_chain,) = _run(chain, x, w, w)
+    assert np.array_equal(np.asarray(o_chain), np.asarray(o_sep))
+    with jax.default_matmul_precision("highest"):
+        want = jax.nn.silu(x @ w[:, :F]) * (x @ w[:, F:])
+    np.testing.assert_allclose(np.asarray(o_chain), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
 def test_dw_adamw_reshape_chain_parity():
     """The row-stream case: dW's (bm, N) blocks feed adamw's (bm*N/128,
     128) blocks through a row-major reshape — same elements per step."""

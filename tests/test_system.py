@@ -61,3 +61,41 @@ def test_serve_cli_smoke():
         capture_output=True, text=True, timeout=600, env=_env())
     assert out.returncode == 0, out.stderr[-2000:]
     assert "served 3 requests" in out.stdout
+
+
+def test_peaks_are_keyed_by_device_kind():
+    """The CPU plans for the v5e row; a TPU kind with no row raises instead
+    of borrowing another chip's peaks."""
+    from types import SimpleNamespace
+
+    from repro.distributed import hlo_analysis as ha
+    assert ha.chip() is ha.CHIPS[ha.PLANNING_TARGET]
+    assert ha.PEAK_FLOPS == ha.CHIPS["TPU v5 lite"].peak_flops
+    v5e = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert ha.chip(v5e).hbm_bw == 819e9
+    unknown = SimpleNamespace(platform="tpu", device_kind="TPU v99")
+    try:
+        ha.chip(unknown)
+    except ValueError as e:
+        assert "TPU v99" in str(e)
+    else:
+        raise AssertionError("an unknown TPU kind must raise")
+
+
+def test_compile_cache_directory(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing else is set; without it
+    the cache goes to the fixed directory inside the checkout."""
+    from repro import compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        jax.config.update("jax_compilation_cache_dir", was)
+        assert compile_cache.enable() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable()
+        assert path == str(Path(__file__).resolve().parents[1]
+                           / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
