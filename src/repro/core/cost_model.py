@@ -50,8 +50,8 @@ MAX_RATIO = 4096
 # Measured-delta corrections (fitted, default OFF)
 #
 # The measured-mode search records cm_vs_measured_delta_pct per bundle;
-# ``python -m repro.tools fit-cost`` distills the accumulated history
-# (benchmarks/history/BENCH_measured_*.json) into a per-op-class
+# ``python -m repro.tools fit-cost`` distills the measured reports
+# (BENCH_measured_*.json) into a per-op-class
 # multiplicative correction table — clamped medians of measured/predicted.
 # The table is consulted only when loaded ($REPRO_COST_CORRECTIONS=<path>
 # or set_corrections(...)); with nothing loaded every factor is exactly

@@ -28,6 +28,7 @@ The 2-op entry points (``generate(a, b, sched)``, ``generate_vfused(a, b)``,
 from __future__ import annotations
 
 import math
+import re
 from typing import Optional, Sequence
 
 import jax
@@ -50,11 +51,18 @@ def _pallas_call(kernel, ops: Sequence[OpSpec], grid: int, in_specs,
     """One pallas_call over ``ops``' operands and scratch.  A compiled
     (non-interpret) call always states its scoped-VMEM limit: the tuned cap,
     else the whole planning budget — the 16 MiB compiler default is smaller
-    than bundles the cost model admits."""
+    than bundles the cost model admits.
+
+    The launch is named after its members: ``name`` becomes the kernel's
+    and its custom call's name (characters outside ``[A-Za-z0-9_]`` read
+    ``_`` there), and ``kernel_metadata`` carries the exact member list as
+    ``{"launch": "a+b"}`` in the custom call's frontend attributes, which
+    the device trace's op text keeps."""
     kwargs = {}
     if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             vmem_limit_bytes=int(vmem_limit or VMEM_BUDGET))
+    launch = "+".join(op.name for op in ops)
     return pl.pallas_call(
         kernel,
         grid=(grid,),
@@ -65,6 +73,8 @@ def _pallas_call(kernel, ops: Sequence[OpSpec], grid: int, in_specs,
         scratch_shapes=[pltpu.VMEM(shape, dt)
                         for op in ops for shape, dt in op.scratch],
         interpret=interpret,
+        name=re.sub(r"\W", "_", launch, flags=re.ASCII),
+        metadata={"launch": launch},
         **kwargs,
     )
 

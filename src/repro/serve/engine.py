@@ -56,6 +56,8 @@ import numpy as np
 from repro.configs.base import ATTN, ModelConfig
 from repro.models import lm
 
+_span = jax.profiler.TraceAnnotation
+
 
 @dataclass
 class Request:
@@ -1647,7 +1649,16 @@ class ServeEngine:
         slot whose occupant retires deterministically this step is reserved
         and starts chunking the next step (its retiree's final decode must
         read the cache first).  A prompt completing its last chunk samples
-        its first token from that chunk's final valid row."""
+        its first token from that chunk's final valid row.
+
+        Every iteration with work in flight is one ``serve.step`` profiler
+        span (``step_num`` = ``stats.steps``) holding its host phases in
+        order: ``serve.admit``, ``serve.stage``, ``serve.dispatch``
+        (args ``step``, ``chunks``, ``active``), ``serve.sync`` (the
+        logits' copy to the host; a second one for the prompt logits),
+        ``serve.sample`` and ``serve.first_token`` (arg ``rids``).  An
+        iteration with nothing in flight and nothing arrived records none
+        (docs/serving.md §Tracing the serve loop)."""
         B = self.batch
         stats = self.stats
         budget = self.prefill_budget
@@ -1677,8 +1688,10 @@ class ServeEngine:
                 stats.prompt_tokens += len(req.prompt)
             pref[b] = ent
 
-        while waiting or any(s is not None for s in slots) or pref:
-            step_i = stats.steps
+        def admit(step_i):
+            """Arrivals, slot claims, reservations and chunk selection.
+            Returns the slots that chunk this step, the reserved
+            ``(slot, request)`` pairs and the decoding slots' mask."""
             arrived = [r for r in waiting if r.arrival <= step_i]
             # claim empty slots now (their first chunk rides this very
             # step); deterministically-retiring slots are only *reserved*
@@ -1719,7 +1732,7 @@ class ServeEngine:
             # so the memory phase the hot experts already saturate isn't
             # stretched further by an extra prefill partner
             if (budget.policy == "eload" and len(sel) > 1
-                    and self.stats.expert_skew >= budget.skew_threshold):
+                    and stats.expert_skew >= budget.skew_threshold):
                 sel = sel[:-1]
                 stats.load_shed_steps += 1
             if paged:
@@ -1743,119 +1756,138 @@ class ServeEngine:
                         pool.release(b)
                         stats.retirements.append((step_i, req.rid,
                                                   "pool_full"))
-            active = np.array([s is not None for s in slots])
-            n_active = int(active.sum())
-            n = len(sel)
+            return sel, reserved, np.array([s is not None for s in slots])
 
-            if n == 0 and n_active == 0:
-                ready = [b for b in pref if pref[b]["ready"] <= step_i]
-                if paged and ready:
-                    # arena deadlock: every schedulable chunk stalled with
-                    # no decoder left to drain blocks — fail the prompt
-                    # with the most work remaining (deterministic) so its
-                    # partial allocation frees the others
-                    b = max(ready, key=lambda b: (len(pref[b]["req"].prompt)
-                                                  - pref[b]["done"], b))
-                    req = pref.pop(b)["req"]
-                    req.done = True
-                    pool.release(b)
-                    stats.retirements.append((step_i, req.rid, "pool_full"))
-                stats.steps += 1                 # idle: future arrivals
+        while waiting or any(s is not None for s in slots) or pref:
+            step_i = stats.steps
+            if not pref and all(s is None for s in slots) and not any(
+                    r.arrival <= step_i for r in waiting):
+                stats.steps += 1       # idle: nothing in flight or arrived
                 continue
-            if paged:
-                bt_dev = jnp.asarray(np.asarray(pool.table, np.int32))
-                stats.blocks_in_use = max(stats.blocks_in_use,
-                                          pool.blocks_in_use)
-
-            if n:
-                ch_valid = [min(C, len(pref[b]["req"].prompt)
-                                - pref[b]["done"]) for b in sel]
-                ch_tok = np.zeros((n, C), np.int32)
-                for j, b in enumerate(sel):
-                    off = pref[b]["done"]
-                    ch_tok[j, :ch_valid[j]] = np.asarray(
-                        pref[b]["req"].prompt[off:off + ch_valid[j]],
-                        np.int32)
-                ret = self._cb_step(n)(
-                    self._step_params, cache, jnp.asarray(last),
-                    jnp.asarray(active),
-                    *((bt_dev,) if paged else ()),
-                    ch_slots=jnp.asarray(np.asarray(sel, np.int32)),
-                    ch_offs=jnp.asarray(
-                        np.asarray([pref[b]["done"] for b in sel],
-                                   np.int32)),
-                    ch_valid=jnp.asarray(np.asarray(ch_valid, np.int32)),
-                    ch_tokens=jnp.asarray(ch_tok))
-                if is_moe:
-                    logits, cache, pf_logits, ecounts = ret
-                else:
-                    logits, cache, pf_logits = ret
-            else:
-                ret = self._cb_step(0)(
-                    self._step_params, cache, jnp.asarray(last),
-                    jnp.asarray(active),
-                    *((bt_dev,) if paged else ()))
-                if is_moe:
-                    logits, cache, ecounts = ret
-                else:
-                    logits, cache = ret
-            if is_moe:
-                stats.add_expert_hits(np.asarray(ecounts))
-
-            stats.steps += 1
-            if n_active:
-                stats.decode_steps += 1
-                stats.slot_steps += n_active
-            else:
-                stats.prefill_only_steps += 1
-            if n and n_active:
-                stats.mixed_steps += 1
-                if self._cb_fused_chunks[n]:
-                    stats.fused_mixed_steps += 1
-            if n:
-                stats.prefill_chunks += n
-                stats.fused_prefill_chunks += len(self._cb_fused_chunks[n])
-
-            logits_np = np.asarray(logits, np.float32)
-            for b in range(B):
-                req = slots[b]
-                if req is None:
-                    continue
-                pos_h[b] += 1
-                tok = self._sample(logits_np[b], req)
-                req.out_tokens.append(tok)
-                stats.tokens += 1
-                last[b] = tok
-                reason = self._retire_reason(req, tok, len(req.out_tokens),
-                                             pos_h[b])
-                if reason:
-                    req.done = True
-                    slots[b] = None
-                    if paged:
+            with jax.profiler.StepTraceAnnotation("serve.step",
+                                                  step_num=step_i):
+                with _span("serve.admit"):
+                    sel, reserved, active = admit(step_i)
+                n_active = int(active.sum())
+                n = len(sel)
+                if n == 0 and n_active == 0:
+                    ready = [b for b in pref if pref[b]["ready"] <= step_i]
+                    if paged and ready:
+                        # arena deadlock: every schedulable chunk stalled
+                        # with no decoder left to drain blocks — fail the
+                        # prompt with the most work remaining
+                        # (deterministic) so its partial allocation frees
+                        # the others
+                        b = max(ready, key=lambda b: (
+                            len(pref[b]["req"].prompt) - pref[b]["done"], b))
+                        req = pref.pop(b)["req"]
+                        req.done = True
                         pool.release(b)
-                    stats.retirements.append((stats.steps - 1, req.rid,
-                                              reason))
-            if n:
-                pf_np = np.asarray(pf_logits, np.float32)
-                for j, b in enumerate(sel):
-                    ent = pref[b]
-                    ent["done"] += ch_valid[j]
-                    pos_h[b] = ent["done"]
-                    if ent["done"] >= len(ent["req"].prompt):
-                        del pref[b]                    # prefill complete
-                        if paged:
-                            # the prompt is fully in cache: index its full
-                            # blocks so later prompts sharing the prefix
-                            # skip those chunks
-                            pool.register(b, ent["req"].prompt, step_i)
-                        self._admit(ent["req"], b, pf_np[j], slots, pos_h,
-                                    last)
-                        if paged and slots[b] is None:
-                            pool.release(b)       # admitted-and-retired
-            for b, req in reserved:
-                # the retiree's final decode ran this step (and, paged, its
-                # blocks were just released) — claim now, chunk next step
-                claim(b, req, stats.steps)
+                        stats.retirements.append((step_i, req.rid,
+                                                  "pool_full"))
+                    stats.steps += 1
+                    continue
+
+                with _span("serve.stage"):
+                    extra = ()
+                    if paged:
+                        extra = (jnp.asarray(np.asarray(pool.table,
+                                                        np.int32)),)
+                        stats.blocks_in_use = max(stats.blocks_in_use,
+                                                  pool.blocks_in_use)
+                    tokens_dev = jnp.asarray(last)
+                    active_dev = jnp.asarray(active)
+                    chunk_kw = {}
+                    if n:
+                        ch_valid = [min(C, len(pref[b]["req"].prompt)
+                                        - pref[b]["done"]) for b in sel]
+                        ch_tok = np.zeros((n, C), np.int32)
+                        for j, b in enumerate(sel):
+                            off = pref[b]["done"]
+                            ch_tok[j, :ch_valid[j]] = np.asarray(
+                                pref[b]["req"].prompt[off:off + ch_valid[j]],
+                                np.int32)
+                        chunk_kw = dict(
+                            ch_slots=jnp.asarray(np.asarray(sel, np.int32)),
+                            ch_offs=jnp.asarray(np.asarray(
+                                [pref[b]["done"] for b in sel], np.int32)),
+                            ch_valid=jnp.asarray(np.asarray(ch_valid,
+                                                            np.int32)),
+                            ch_tokens=jnp.asarray(ch_tok))
+                with _span("serve.dispatch", step=step_i, chunks=n,
+                           active=n_active):
+                    ret = self._cb_step(n)(
+                        self._step_params, cache, tokens_dev, active_dev,
+                        *extra, **chunk_kw)
+                logits, cache = ret[:2]
+                pf_logits = ret[2] if n else None
+
+                stats.steps += 1
+                if n_active:
+                    stats.decode_steps += 1
+                    stats.slot_steps += n_active
+                else:
+                    stats.prefill_only_steps += 1
+                if n and n_active:
+                    stats.mixed_steps += 1
+                    if self._cb_fused_chunks[n]:
+                        stats.fused_mixed_steps += 1
+                if n:
+                    stats.prefill_chunks += n
+                    stats.fused_prefill_chunks += len(
+                        self._cb_fused_chunks[n])
+
+                with _span("serve.sync"):
+                    logits_np = np.asarray(logits, np.float32)
+                    if is_moe:
+                        stats.add_expert_hits(np.asarray(ret[-1]))
+                with _span("serve.sample"):
+                    for b in range(B):
+                        req = slots[b]
+                        if req is None:
+                            continue
+                        pos_h[b] += 1
+                        tok = self._sample(logits_np[b], req)
+                        req.out_tokens.append(tok)
+                        stats.tokens += 1
+                        last[b] = tok
+                        reason = self._retire_reason(
+                            req, tok, len(req.out_tokens), pos_h[b])
+                        if reason:
+                            req.done = True
+                            slots[b] = None
+                            if paged:
+                                pool.release(b)
+                            stats.retirements.append((stats.steps - 1,
+                                                      req.rid, reason))
+                if n:
+                    with _span("serve.sync"):
+                        pf_np = np.asarray(pf_logits, np.float32)
+                    complete = []                    # prefill complete
+                    for j, b in enumerate(sel):
+                        ent = pref[b]
+                        ent["done"] += ch_valid[j]
+                        pos_h[b] = ent["done"]
+                        if ent["done"] >= len(ent["req"].prompt):
+                            del pref[b]
+                            complete.append((b, ent["req"], pf_np[j]))
+                    if complete:
+                        with _span("serve.first_token", rids=" ".join(
+                                str(r.rid) for _, r, _ in complete)):
+                            for b, req, row in complete:
+                                if paged:
+                                    # the prompt is fully in cache: index
+                                    # its full blocks so later prompts
+                                    # sharing the prefix skip those chunks
+                                    pool.register(b, req.prompt, step_i)
+                                self._admit(req, b, row, slots, pos_h, last)
+                                if paged and slots[b] is None:
+                                    pool.release(b)  # admitted-and-retired
+                for b, req in reserved:
+                    # the retiree's final decode ran this step (and, paged,
+                    # its blocks were just released) — claim now, chunk
+                    # next step
+                    claim(b, req, stats.steps)
         if paged:
             stats.evictions = pool.evictions - pool_base[0]
             stats.prefix_hits = pool.prefix_hits - pool_base[1]

@@ -19,9 +19,10 @@ vs evictable-cached blocks), the prefix-index counters (hits, tokens
 reused, trie size, evictions, COW copies), and one row per batch slot
 with its mapped block-table prefix.
 
-``fit-cost`` distills the accumulated cm-vs-measured deltas in the CI
-benchmark trajectory (``benchmarks/history/BENCH_measured_*.json``) into
-a per-op-class correction table for the roofline cost model — clamped
+``fit-cost`` distills the cm-vs-measured deltas of the
+``BENCH_measured_*.json`` reports in a directory (``benchmarks.run
+--measure`` writes them into the working directory) into a per-op-class
+correction table for the roofline cost model — clamped
 medians of measured/predicted per class (core/cost_model.op_class).  The
 table is inert until loaded ($REPRO_COST_CORRECTIONS=<path> or
 ``cost_model.set_corrections``); nothing in the default model changes.
@@ -308,8 +309,8 @@ def main(argv=None) -> int:
     ki.set_defaults(fn=kv_inspect)
     fc = sub.add_parser("fit-cost",
                         help="fit per-op-class cost-model corrections from "
-                             "the benchmark history")
-    fc.add_argument("--history", default="benchmarks/history",
+                             "measured benchmark reports")
+    fc.add_argument("--history", default=".",
                     help="directory holding BENCH_measured_*.json reports")
     fc.add_argument("--out", default=None,
                     help="write the correction table here (activate via "

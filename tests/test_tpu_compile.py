@@ -125,3 +125,18 @@ def test_planned_decode_program_compiles_for_v5e(one_chip, engine):
     assert prog.n_fused >= 1 and not prog.interpret
     for step in prog.steps:
         _compile(step.call, step.ops, one_chip)
+
+
+def test_fused_launch_is_named_in_compiled_text(one_chip, engine):
+    """The compiled custom call of a fused bundle is named after its
+    members and carries them in ``kernel_metadata``, which the device
+    trace's op text keeps."""
+    import json
+    import re
+    prog = engine.build_decode_program(prefill_chunks=1, interpret=False)
+    step = next(s for s in prog.steps if s.fused)
+    text = _compile(step.call, step.ops, one_chip).as_text()
+    launch = "+".join(step.members)
+    assert f'"launch":{json.dumps(launch)}' in text
+    name = re.sub(r"\W", "_", launch, flags=re.ASCII)
+    assert re.search(rf"%{name}(\.\d+)? = .*custom-call\(", text)
