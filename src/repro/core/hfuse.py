@@ -37,13 +37,21 @@ from jax.experimental.pallas import tpu as pltpu
 import jax.numpy as jnp
 
 from repro.core.cost_model import VMEM_BUDGET, Schedule
-from repro.core.op_spec import Operand, OpSpec
+from repro.core.op_spec import DMA_SEMAPHORE, Operand, OpSpec
 
 
 def _block_spec(operand: Operand, index_map) -> pl.BlockSpec:
     if operand.smem:
         return pl.BlockSpec(memory_space=pltpu.SMEM)
+    if operand.hbm:
+        return pl.BlockSpec(memory_space=pl.ANY)
     return pl.BlockSpec(operand.block_shape, index_map)
+
+
+def _scratch_shape(shape, dtype):
+    if dtype == DMA_SEMAPHORE:
+        return pltpu.SemaphoreType.DMA(tuple(shape))
+    return pltpu.VMEM(shape, dtype)
 
 
 def _pallas_call(kernel, ops: Sequence[OpSpec], grid: int, in_specs,
@@ -70,7 +78,7 @@ def _pallas_call(kernel, ops: Sequence[OpSpec], grid: int, in_specs,
         out_specs=out_specs,
         out_shape=[jax.ShapeDtypeStruct(o.shape, o.dtype)
                    for op in ops for o in op.outputs],
-        scratch_shapes=[pltpu.VMEM(shape, dt)
+        scratch_shapes=[_scratch_shape(shape, dt)
                         for op in ops for shape, dt in op.scratch],
         interpret=interpret,
         name=re.sub(r"\W", "_", launch, flags=re.ASCII),

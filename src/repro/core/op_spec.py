@@ -10,8 +10,9 @@ its steps between two ops the way HFUSE partitions the thread space.
 Contract for ``body``:
   body(step, *in_refs, *out_refs, *scratch_refs) — ``step`` is the
   op-local grid step (a traced scalar); refs are VMEM blocks selected by
-  the index maps (SMEM tables for ``Operand.smem``), then one VMEM buffer
-  per ``OpSpec.scratch`` entry, persistent across the op's steps.
+  the index maps (SMEM tables for ``Operand.smem``, whole HBM arrays for
+  ``Operand.hbm``), then one scratch buffer per ``OpSpec.scratch`` entry
+  (VMEM, or DMA semaphores), persistent across the op's steps.
   The body must not call pl.program_id itself (the fused kernel owns it).
 """
 from __future__ import annotations
@@ -34,15 +35,21 @@ class Operand:
     ``smem=True`` marks a small int32 table (per-slot lengths, chunk
     offsets, block-table rows) that the kernel reads as scalars: it lives
     whole in scalar memory, so ``block_shape`` equals ``shape``, the index
-    map is never consulted and it costs no VMEM."""
+    map is never consulted and it costs no VMEM.
+
+    ``hbm=True`` marks an array the kernel leaves in HBM and copies from
+    itself (``pltpu.make_async_copy`` into ``OpSpec.scratch`` buffers):
+    ``block_shape`` equals ``shape``, the index map is never consulted and
+    the operand itself costs no VMEM — its buffers are the op's scratch."""
     shape: tuple[int, ...]
     dtype: Any
     block_shape: tuple[int, ...]
     index_map: Callable[[Any], tuple]      # op-local step -> block indices
     smem: bool = False
+    hbm: bool = False
 
     def block_bytes(self) -> int:
-        if self.smem:
+        if self.smem or self.hbm:
             return 0
         return vmem_bytes_of(self.block_shape, self.dtype)
 
@@ -63,6 +70,24 @@ def smem_operand(shape: tuple[int, ...]) -> Operand:
     """A whole int32 array in scalar memory (see ``Operand.smem``)."""
     return Operand(tuple(shape), jnp.int32, tuple(shape),
                    lambda s: (0,) * len(shape), smem=True)
+
+
+def hbm_operand(shape: tuple[int, ...], dtype) -> Operand:
+    """A whole array left in HBM for the body to copy from (see
+    ``Operand.hbm``)."""
+    return Operand(tuple(shape), dtype, tuple(shape),
+                   lambda s: (0,) * len(shape), hbm=True)
+
+
+# ``OpSpec.scratch`` dtype of an array of DMA semaphores (no VMEM)
+DMA_SEMAPHORE = "dma_semaphore"
+
+
+def scratch_bytes(shape: Sequence[int], dtype) -> int:
+    """VMEM one ``OpSpec.scratch`` entry takes (semaphores take none)."""
+    if dtype == DMA_SEMAPHORE:
+        return 0
+    return vmem_bytes_of(shape, dtype)
 
 
 @dataclass
@@ -95,9 +120,10 @@ class OpSpec:
     # binding then reads and rewrites the same state key.
     in_names: tuple[str, ...] = ()
     out_names: tuple[str, ...] = ()
-    # Persistent VMEM buffers ((shape, dtype) each) handed to the body after
-    # its output refs — carries such as online-softmax statistics that need
-    # no HBM copy.
+    # Persistent scratch ((shape, dtype) each) handed to the body after its
+    # output refs: VMEM buffers — carries such as online-softmax statistics
+    # that need no HBM copy, or the landing buffers of the body's own DMAs —
+    # and, with dtype ``DMA_SEMAPHORE``, arrays of DMA semaphores.
     scratch: tuple[tuple[tuple[int, ...], Any], ...] = ()
 
     def __post_init__(self):
@@ -119,7 +145,7 @@ class OpSpec:
         body's live intermediates (a stitched chain's resident block, an
         attention score tile) ride in ``extra_vmem_bytes``."""
         return (sum(o.block_bytes() for o in (*self.inputs, *self.outputs))
-                + sum(vmem_bytes_of(shape, dt) for shape, dt in self.scratch)
+                + sum(scratch_bytes(shape, dt) for shape, dt in self.scratch)
                 + self.extra_vmem_bytes)
 
     @property

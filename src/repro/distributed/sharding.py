@@ -313,12 +313,16 @@ def tp_param_pspec(name: str, ndim: int, axis: str = "model") -> P:
     return P()
 
 
-def tp_cache_pspec(name: str, ndim: int, axis: str = "model") -> P:
+def tp_cache_pspec(name: str, ndim: int, axis: str = "model", *,
+                   flat_heads: bool = False) -> P:
     """PartitionSpec for a KV-cache leaf: k/v shard their head axis (-2,
     both for contiguous ``(B,S,Hkv,D)`` / stacked ``(L,B,S,Hkv,D)`` leaves
-    and for the paged ``(blocks,bs,Hkv,D)`` arena); positions and block
-    tables replicate — the per-slot ``(B,)`` position contract and the
-    slot manager are shard-invariant."""
+    and for the paged ``(blocks,bs,Hkv,D)`` arena; -1 for ``flat_heads``
+    leaves ``(L,B,S,Hkv*D)``, whose even split is the same head split);
+    positions and block tables replicate — the per-slot ``(B,)`` position
+    contract and the slot manager are shard-invariant."""
     if name in ("k", "v"):
+        if flat_heads:
+            return P(*([None] * (ndim - 1) + [axis]))
         return P(*([None] * (ndim - 2) + [axis, None]))
     return P()

@@ -18,6 +18,22 @@ compute-bound GEMMs in one fused launch (serve/kv_pool.py owns the arena).
 The page gather reassembles exactly the contiguous kernel's ``(ck, Hkv,
 D)`` block, so paged and contiguous attention are BITWISE equal for equal
 logical cache content (tests/test_kv_paged_attention.py).
+
+Stacked form (``stacked_layers=L``): the k/v operands are the whole
+layer-stacked cache with heads flattened into rows, ``(L, B, S, Hkv * D)``,
+left in HBM (``Operand.hbm``), and a ``(1, 1)`` int32 operand ("layer", in
+scalar memory) says which layer to read.  The body copies each step's
+``(ck, Hkv * D)`` k and v rows itself into two-slot VMEM scratch, starting
+the copy for its next step before it computes this one — the double
+buffering BlockSpec pipelining gives the other forms — and reads head
+``g`` as lanes ``[g * D, (g + 1) * D)``.  The flat rows are what keep the
+cache where it lies: on a TPU, XLA lays a bf16 ``(.., S, Hkv, D)`` array
+with ``D = 64`` out sequence-minor, which a kernel cannot read without a
+relayout copy, while ``(.., S, Hkv * D)`` rows are laid out exactly as
+the kernel reads them, unpadded.  The serve engine's layer scan then hands attention the
+cache it carries, with no per-layer slice.  Per head the blocks and the
+math are the contiguous form's, so both are BITWISE equal on
+``cache[l].reshape(B, S, Hkv, D)`` (tests/test_decode_attention_stacked.py).
 """
 from __future__ import annotations
 
@@ -26,8 +42,10 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.op_spec import MIN_BLOCK_ROWS, OpSpec, Operand, smem_operand
+from repro.core.op_spec import (DMA_SEMAPHORE, MIN_BLOCK_ROWS, OpSpec,
+                                Operand, hbm_operand, smem_operand)
 
 NEG_INF = -1e30
 
@@ -63,7 +81,7 @@ def attend_group(qg, k, v, keep, m_prev, l_prev, acc):
 def decode_attention_op(B: int, S: int, H: int, Hkv: int, D: int,
                         dtype=jnp.bfloat16, ck: int = 1024,
                         length=None, dynamic_length: bool = False,
-                        block_table=None) -> OpSpec:
+                        block_table=None, stacked_layers=None) -> OpSpec:
     """q: (B,H,D); cache k,v: (B,S,Hkv,D); out o: (B,H,D) fp32.
 
     Grid: B * (S // ck) steps, batch-major.  `length` (static) masks the
@@ -83,14 +101,22 @@ def decode_attention_op(B: int, S: int, H: int, Hkv: int, D: int,
     ``(B, max_blocks)`` int32 operand ("bt", in scalar memory) maps each
     slot's logical pages to arena blocks.  Requires ``ck % block_size ==
     0`` so every kv-chunk is a whole number of pages.
+
+    ``stacked_layers=L`` switches to the stacked form: k/v are the whole
+    ``(L, B, S, Hkv * D)`` cache in HBM and a ``(1, 1)`` int32 operand
+    ("layer", in scalar memory, ahead of "len") picks the layer; the body
+    double-buffers its own block copies (module docstring).
     """
     assert S % ck == 0 and H % Hkv == 0
     assert not (dynamic_length and length is not None)
     nk = S // ck
+    grid = B * nk
     rep = H // Hkv
     scale = 1.0 / math.sqrt(D)
     valid_len = S if length is None else int(length)
     paged = block_table is not None
+    stacked = stacked_layers is not None
+    assert not (paged and stacked)
     if paged:
         num_blocks, bs = block_table
         assert ck % bs == 0 and S % bs == 0
@@ -98,15 +124,38 @@ def decode_attention_op(B: int, S: int, H: int, Hkv: int, D: int,
         npc = ck // bs                       # pages per kv-chunk
 
     def body(step, *refs):
-        if paged:
-            bt_ref, refs = refs[0], refs[1:]
+        refs = list(refs)
+        bt_ref = refs.pop(0) if paged else None
+        layer_ref = refs.pop(0) if stacked else None
+        len_ref = refs.pop(0) if dynamic_length else None
+        q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *scratch = refs
         b, j = step // nk, step % nk
-        if dynamic_length:
-            len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref = refs
-            cur_len = len_ref[b, 0]
-        else:
-            q_ref, k_ref, v_ref, o_ref, m_ref, l_ref = refs
-            cur_len = valid_len
+        cur_len = len_ref[b, 0] if dynamic_length else valid_len
+        if stacked:
+            k_buf, v_buf, sem = scratch
+            slot = step % 2
+
+            def copies(s, into):
+                """Step ``s``'s k and v row copies into buffer ``into``."""
+                rows = pl.ds((s % nk) * ck, ck)
+                layer = layer_ref[0, 0]
+                return [pltpu.make_async_copy(src.at[layer, s // nk, rows],
+                                              buf.at[into], sem.at[i, into])
+                        for i, (src, buf) in enumerate(((k_ref, k_buf),
+                                                        (v_ref, v_buf)))]
+
+            @pl.when(step == 0)
+            def _():
+                for c in copies(step, slot):
+                    c.start()
+
+            @pl.when(step + 1 < grid)
+            def _():
+                for c in copies(step + 1, 1 - slot):
+                    c.start()
+
+            for c in copies(step, slot):
+                c.wait()
 
         @pl.when(j == 0)
         def _():
@@ -121,6 +170,9 @@ def decode_attention_op(B: int, S: int, H: int, Hkv: int, D: int,
             if paged:
                 k = gather_pages(k_ref, bt_ref, b, j * npc, npc, g)
                 v = gather_pages(v_ref, bt_ref, b, j * npc, npc, g)
+            elif stacked:
+                lanes = slice(g * D, (g + 1) * D)
+                k, v = k_buf[slot, :, lanes], v_buf[slot, :, lanes]
             else:
                 k, v = k_ref[0, :, g, :], v_ref[0, :, g, :]     # (ck, D)
             qg = q_ref[0, rows, :].astype(jnp.float32) * scale  # (rep, D)
@@ -152,16 +204,25 @@ def decode_attention_op(B: int, S: int, H: int, Hkv: int, D: int,
                                        length=length,
                                        dynamic_length=dynamic_length,
                                        block_table=block_table)
+    elif stacked:
+        L = int(stacked_layers)
+        kv = (hbm_operand((L, B, S, Hkv * D), dtype),
+              hbm_operand((L, B, S, Hkv * D), dtype))
+        bt_in, suffix, bt_name, shrink = (), f"_L{L}", (), None
     else:
         kv = (Operand((B, S, Hkv, D), dtype, (1, ck, Hkv, D),
                       lambda s: (s // nk, s % nk, 0, 0)),
               Operand((B, S, Hkv, D), dtype, (1, ck, Hkv, D),
                       lambda s: (s // nk, s % nk, 0, 0)))
         bt_in, suffix, bt_name, shrink = (), "", (), None
+    layer_in = (smem_operand((1, 1)),) if stacked else ()
+    # two k and two v landing buffers, and one semaphore per copy in flight
+    scratch = (((2, ck, Hkv * D), dtype), ((2, ck, Hkv * D), dtype),
+               ((2, 2), DMA_SEMAPHORE)) if stacked else ()
     return OpSpec(
         name=f"decode_attn_B{B}_S{S}_H{H}kv{Hkv}{suffix}",
-        grid=B * nk, body=body,
-        inputs=bt_in + len_in
+        grid=grid, body=body,
+        inputs=bt_in + layer_in + len_in
         + (Operand((B, H, D), dtype, (1, H, D), lambda s: (s // nk, 0, 0)),)
         + kv,
         outputs=(Operand((B, H, D), jnp.float32, (1, H, D),
@@ -177,6 +238,7 @@ def decode_attention_op(B: int, S: int, H: int, Hkv: int, D: int,
         tag="framework:decode_attention",
         # one KV head's fp32 score/probability tiles (rep, ck) live at once
         extra_vmem_bytes=2 * rep * ck * 4,
-        in_names=bt_name + (("len",) if dynamic_length else ())
-        + ("q", "k", "v"),
-        out_names=("o", "m", "l"))
+        in_names=bt_name + (("layer",) if stacked else ())
+        + (("len",) if dynamic_length else ()) + ("q", "k", "v"),
+        out_names=("o", "m", "l"),
+        scratch=scratch)

@@ -178,6 +178,8 @@ class ServeStats:
     expert_hits: list = field(default_factory=list)  # per-expert routed
     #                               decode-token count, layer-summed
     load_shed_steps: int = 0      # steps where eload shed a coresident chunk
+    kv_in_place_steps: int = 0    # dispatched steps whose layer scan kept
+    #                               the stacked K/V cache in place
 
     @property
     def occupancy(self) -> float:
@@ -247,6 +249,7 @@ class ServeStats:
             "expert_hits": list(self.expert_hits),
             "expert_skew": round(self.expert_skew, 3),
             "load_shed_steps": self.load_shed_steps,
+            "kv_in_place_steps": self.kv_in_place_steps,
         }
 
 
@@ -501,6 +504,18 @@ class ServeEngine:
             return self._aligned_len()
         return self.max_len
 
+    @property
+    def kv_in_place(self) -> bool:
+        """Whether the executed continuous step keeps the K/V cache in place
+        across its layer scan: true for a stacked (``count > 1``)
+        contiguous run.  The cache is then ``(L, B, S, Hkv * D)`` per
+        leaf, the scan carries it whole, and decode attention reads layer
+        ``l`` of it in HBM (docs/serving.md §The KV cache in place).  Paged
+        arenas and single-layer runs keep their per-layer form."""
+        return (getattr(self, "executed", False)
+                and not getattr(self, "paged_kv", False)
+                and lm.layer_runs(self.cfg)[0].count > 1)
+
     @staticmethod
     def _kv_chunk(S: int) -> int:
         """KV rows per attention grid step: the largest 128-multiple <= 512
@@ -601,9 +616,11 @@ class ServeEngine:
         norm2 = dataclasses.replace(rmsnorm_op(R=B, d=d, dtype=dt, bm=B),
                                     name="decode_norm2")
         ck = self._kv_chunk(S)
-        att = decode_attention_op(B=B, S=S, H=H, Hkv=Hkv, D=D, dtype=dt,
-                                  ck=ck, dynamic_length=dynamic_length,
-                                  block_table=bt)
+        att = decode_attention_op(
+            B=B, S=S, H=H, Hkv=Hkv, D=D, dtype=dt, ck=ck,
+            dynamic_length=dynamic_length, block_table=bt,
+            stacked_layers=(lm.layer_runs(cfg)[0].count
+                            if self.kv_in_place else None))
         # decode-slot projection: MoE router when the model routes, else the
         # FFN in-projection — weight streaming dominates at serving batch
         # (memory-bound; the honest fig_framework finding), so the planner
@@ -763,7 +780,9 @@ class ServeEngine:
         norm's output slot projects QKV, applies RoPE at each slot's own
         position and scatters k/v into each slot's cache row (masked by the
         per-slot ``act`` vector, so prefilling/idle slots never see a stale
-        garbage write); the attention output slot applies W_o and the
+        garbage write; with ``kv_in_place`` the row lands in layer
+        ``state["layer"]`` of the stacked cache, which decode attention
+        then reads in place); the attention output slot applies W_o and the
         residual; the projection output slot finishes the MLP and the
         second residual.  Each of the ``prefill_chunks`` flash-prefill ops
         reads its own slot's cache rows (``pf{i}_slot``) at its own chunk
@@ -815,6 +834,8 @@ class ServeEngine:
 
         paged = getattr(self, "paged_kv", False)
         bs = self.kv_block_size if paged else 0
+        in_place = self.kv_in_place
+        S = self._aligned_len()
 
         def qkv_put(state, qkv):
             # the planned QKV matmul's output: split heads, RoPE at each
@@ -835,20 +856,25 @@ class ServeEngine:
             k = layers.rope(k, positions, cfg.rope_theta, cfg.rope_fraction)
             state = dict(state)
             state["q"] = q[:, 0]
+            k, v = k[:, 0], v[:, 0]                              # (B, Hkv, D)
             rows = jnp.arange(B)
             if paged:
                 rows = state["bt"][rows, state["pos"] // bs]    # arena blocks
-                cols = state["pos"] % bs
+                idx = (rows, state["pos"] % bs)
+            elif in_place:
+                # layer `layer` of the stacked cache, heads flat in a row
+                idx = (state["layer"], rows, state["pos"])
+                k, v = k.reshape(B, Hkv * D), v.reshape(B, Hkv * D)
             else:
-                cols = state["pos"]
+                idx = (rows, state["pos"])
             # act-masked scatter: only decoding slots land k/v — a
             # prefilling slot's row at `pos` is live chunk data this very
             # step and must not be clobbered by its stale last-token write
-            act = state["act"][:, None, None]
-            k_row = jnp.where(act, k[:, 0], state["k_cache"][rows, cols])
-            v_row = jnp.where(act, v[:, 0], state["v_cache"][rows, cols])
-            state["k_cache"] = state["k_cache"].at[rows, cols].set(k_row)
-            state["v_cache"] = state["v_cache"].at[rows, cols].set(v_row)
+            act = state["act"].reshape((B,) + (1,) * (k.ndim - 1))
+            k_row = jnp.where(act, k, state["k_cache"][idx])
+            v_row = jnp.where(act, v, state["v_cache"][idx])
+            state["k_cache"] = state["k_cache"].at[idx].set(k_row)
+            state["v_cache"] = state["v_cache"].at[idx].set(v_row)
             return state
 
         psum_axis = axis if tp > 1 else None   # row-sharded W_o / W_out
@@ -888,6 +914,9 @@ class ServeEngine:
                               .reshape(B, 1).astype(jnp.int32))}
         if paged:
             att_in["bt"] = "bt"               # (B, max_blocks) device table
+        if in_place:
+            att_in["layer"] = Slot(get=lambda s: jnp.reshape(
+                s["layer"], (1, 1)).astype(jnp.int32))
         reg.bind(att_name, q="q", k="k_cache", v="v_cache",
                  inputs=att_in,
                  outputs={"o": Slot(put=att_put), "m": "attn_m",
@@ -977,12 +1006,19 @@ class ServeEngine:
             # the chunk reads ITS OWN slot's cache rows — a (S, Hkv, D)
             # gather the decode scatter never touches (act masks that slot).
             # Paged: k/v are the WHOLE shared arena and the chunk's slot
-            # contributes its (1, max_blocks) table row instead.
+            # contributes its (1, max_blocks) table row instead.  In place:
+            # the slot's rows of this layer, heads unflattened.
             if paged:
                 pf_in = {"off": f"pf{i}_off", "q": f"pf{i}_q",
                          "k": "k_cache", "v": "v_cache",
                          "bt": Slot(get=lambda s, i=i:
                                     s["bt"][s[f"pf{i}_slot"]][None])}
+            elif in_place:
+                def slot_rows(name, i=i):
+                    return Slot(get=lambda s: s[name][
+                        s["layer"], s[f"pf{i}_slot"]].reshape(S, Hkv, D))
+                pf_in = {"off": f"pf{i}_off", "q": f"pf{i}_q",
+                         "k": slot_rows("k_cache"), "v": slot_rows("v_cache")}
             else:
                 pf_in = {"off": f"pf{i}_off", "q": f"pf{i}_q",
                          "k": Slot(get=lambda s, i=i:
@@ -994,8 +1030,8 @@ class ServeEngine:
 
     def _layer_state(self, p, kv, x, pos, act):
         """State pytree for ONE layer of the executed program: ``p`` is the
-        layer's block params, ``kv`` its ``{"k", "v"}`` cache leaves (the
-        scan over stacked runs feeds per-layer slices of both); ``pos`` is
+        layer's block params, ``kv`` its ``{"k", "v"}`` cache leaves (in
+        place, the whole stacked cache the scan carries); ``pos`` is
         the per-slot position vector (B,), ``act`` the per-slot decoding
         mask (B,) bool gating the decode k/v scatter."""
         state = {
@@ -1072,9 +1108,9 @@ class ServeEngine:
         from jax.tree_util import tree_map_with_path
         from repro.distributed import sharding as shd
         return tree_map_with_path(
-            lambda path, leaf: shd.tp_cache_pspec(path[-1].key,
-                                                  jnp.ndim(leaf),
-                                                  self.shard_axis),
+            lambda path, leaf: shd.tp_cache_pspec(
+                path[-1].key, jnp.ndim(leaf), self.shard_axis,
+                flat_heads=self.kv_in_place),
             jax.eval_shape(self._init_slot_cache_local))
 
     def _tp_place(self, tree, specs):
@@ -1210,18 +1246,26 @@ class ServeEngine:
         """The slot cache: ``lm.init_cache`` with the scalar wave position
         replaced by the per-slot position vector (B,).  Paged: the k/v
         leaves are the flat ``(kv_blocks, block_size, Hkv, D)`` arena the
-        block tables index into, not per-slot regions."""
+        block tables index into, not per-slot regions.  In place
+        (``kv_in_place``): each k/v leaf is ``(L, B, S, Hkv * D)``, a
+        slot's row holding its heads side by side, which the stacked decode
+        attention reads without a relayout."""
+        run = lm.layer_runs(self.cfg)[0]
+        cfg = self.cfg
         if getattr(self, "paged_kv", False):
-            run = lm.layer_runs(self.cfg)[0]
-            dt = jnp.dtype(self.cfg.dtype)
             shape = (self.kv_blocks, self.kv_block_size,
-                     self.cfg.num_kv_heads, self.cfg.resolved_head_dim)
-            return {"pos": jnp.zeros((self.batch,), jnp.int32),
-                    run.name: {"k": jnp.zeros(shape, dt),
-                               "v": jnp.zeros(shape, dt)}}
-        cache = lm.init_cache(self.cfg, self.batch, self.cache_len)
-        cache["pos"] = jnp.zeros((self.batch,), jnp.int32)
-        return cache
+                     cfg.num_kv_heads, cfg.resolved_head_dim)
+        elif self.kv_in_place:
+            shape = (run.count, self.batch, self.cache_len,
+                     cfg.num_kv_heads * cfg.resolved_head_dim)
+        else:
+            cache = lm.init_cache(cfg, self.batch, self.cache_len)
+            cache["pos"] = jnp.zeros((self.batch,), jnp.int32)
+            return cache
+        dt = jnp.dtype(cfg.dtype)
+        return {"pos": jnp.zeros((self.batch,), jnp.int32),
+                run.name: {"k": jnp.zeros(shape, dt),
+                           "v": jnp.zeros(shape, dt)}}
 
     def _slot_axes(self):
         """vmap axes pytree for the slot cache: batch lives on axis 0 of
@@ -1310,10 +1354,13 @@ class ServeEngine:
         request's first-token logits.
 
         Stacked configs (one ATTN run with ``count > 1``) scan the
-        per-layer body over the layer-stacked param/cache leaves — the
-        program runs once per layer inside ``lax.scan``, carrying the
-        decode hidden (B, d) and each chunk's (C, d) hidden between
-        layers.  Under tensor parallelism the whole step body runs inside
+        per-layer body over the layer-stacked params — the program runs
+        once per layer inside ``lax.scan``, carrying the decode hidden
+        (B, d), each chunk's (C, d) hidden and the whole stacked K/V cache
+        between layers (``kv_in_place``): layer ``l`` writes its new rows
+        into the carried cache and decode attention reads layer ``l`` of
+        it where it lies, so no layer's cache is sliced out or written
+        back.  Under tensor parallelism the whole step body runs inside
         ``jax.shard_map``: every shard executes its own shard-local
         fused program, the output projections psum, and logits/positions
         come out replicated."""
@@ -1334,6 +1381,7 @@ class ServeEngine:
         D = cfg.resolved_head_dim
         paged = getattr(self, "paged_kv", False)
         bs = self.kv_block_size if paged else 0
+        in_place = self.kv_in_place
         C = self.chunk_rows()
         program = self.build_decode_program(prefill_chunks=n)
         # a chunk counts as fused when it shares a launch with any
@@ -1351,17 +1399,22 @@ class ServeEngine:
             "fused_members": [sorted(ms) for ms in program.fused_members],
             "steps": program.describe(),
             "interpret": program.interpret,
+            "kv_in_place": in_place,
         }
         is_moe = cfg.moe is not None
 
-        def layer_step(p, kv, x, pos, act, bt, chs, ch_slots, ch_offs):
+        def layer_step(p, kv, x, pos, act, bt, chs, ch_slots, ch_offs,
+                       layer=None):
             """One transformer layer over the whole slot state: the decode
             step for all B slots plus the riding chunks' pre/post-work.
             ``chs`` is the tuple of per-chunk (C, d) hiddens this layer
-            consumes and reproduces (the scan carry)."""
+            consumes and reproduces (the scan carry).  In place, ``kv`` is
+            the whole stacked cache and ``layer`` this layer's index."""
             state = self._layer_state(p, kv, x, pos, act)
             if paged:
                 state["bt"] = bt              # (B, max_blocks) int32 tables
+            if in_place:
+                state["layer"] = layer
 
             # chunk pre-work: norm + QKV + RoPE at absolute chunk
             # positions, then land the chunk's k/v in its slot's cache rows
@@ -1396,6 +1449,12 @@ class ServeEngine:
                         kp[0].reshape(npg, bs, *kp.shape[2:]).astype(kc.dtype))
                     vc = vc.at[blks].set(
                         vp[0].reshape(npg, bs, *vp.shape[2:]).astype(vc.dtype))
+                elif in_place:
+                    at = (layer, ch_slots[i], ch_offs[i], 0)
+                    kc = jax.lax.dynamic_update_slice(
+                        kc, kp.reshape(1, 1, C, -1).astype(kc.dtype), at)
+                    vc = jax.lax.dynamic_update_slice(
+                        vc, vp.reshape(1, 1, C, -1).astype(vc.dtype), at)
                 else:
                     kc = jax.lax.dynamic_update_slice(
                         kc, kp.astype(kc.dtype),
@@ -1459,31 +1518,25 @@ class ServeEngine:
                     x1, kv_new, chs, ecounts = out
                 else:
                     x1, kv_new, chs = out
-            elif is_moe:
-                # the scan carries a per-expert hit accumulator so the
-                # host sees layer-summed counts per step
-                def body(carry, xs):
-                    xc, chc, cnt = carry
-                    p_l, kv_l = xs
-                    xn, kv_out, chn, c_l = layer_step(p_l, kv_l, xc, pos,
-                                                      active, bt, chc,
-                                                      ch_slots, ch_offs)
-                    return (xn, chn, cnt + c_l), kv_out
-                (x1, chs, ecounts), kv_new = maybe_scan(
-                    body, (x[:, 0], chs,
-                           jnp.zeros((cfg.moe.num_experts,), jnp.int32)),
-                    (params[run.name], cache[run.name]), length=L)
             else:
+                # the whole stacked cache rides the carry (in place); the
+                # MoE scan also carries a per-expert hit accumulator so
+                # the host sees layer-summed counts per step
                 def body(carry, xs):
-                    xc, chc = carry
-                    p_l, kv_l = xs
-                    xn, kv_out, chn = layer_step(p_l, kv_l, xc, pos,
-                                                 active, bt, chc,
-                                                 ch_slots, ch_offs)
-                    return (xn, chn), kv_out
-                (x1, chs), kv_new = maybe_scan(
-                    body, (x[:, 0], chs),
-                    (params[run.name], cache[run.name]), length=L)
+                    xc, chc, kv, cnt = carry
+                    p_l, l = xs
+                    xn, kv, chn, *c_l = layer_step(p_l, kv, xc, pos, active,
+                                                   bt, chc, ch_slots,
+                                                   ch_offs, layer=l)
+                    if is_moe:
+                        cnt = cnt + c_l[0]
+                    return (xn, chn, kv, cnt), None
+                cnt0 = (jnp.zeros((cfg.moe.num_experts,), jnp.int32)
+                        if is_moe else None)
+                (x1, chs, kv_new, ecounts), _ = maybe_scan(
+                    body, (x[:, 0], chs, cache[run.name], cnt0),
+                    (params[run.name], jnp.arange(L, dtype=jnp.int32)),
+                    length=L)
 
             xf = layers.apply_norm(cfg, params["final_norm"],
                                    x1[:, None, :].astype(x.dtype))
@@ -1531,9 +1584,17 @@ class ServeEngine:
         return step
 
     def _cb_step(self, n_chunks: int):
+        """The jitted continuous step.  Where its kernels compile (any
+        backend but the CPU), the step donates its cache argument, so the
+        cache it returns reuses the input's buffers and the K/V rows a step
+        writes land where the cache lies.  The CPU's interpret mode keeps
+        its input cache alive: a caller there may run a step twice on one
+        state."""
         if n_chunks not in self._cb_steps:
-            self._cb_steps[n_chunks] = jax.jit(
-                self._make_cb_step(n_chunks))
+            step = self._make_cb_step(n_chunks)
+            donate = () if self.cb_program_info[n_chunks]["interpret"] \
+                else (1,)
+            self._cb_steps[n_chunks] = jax.jit(step, donate_argnums=donate)
         return self._cb_steps[n_chunks]
 
     # ------------------------------------------------------------------
@@ -1654,7 +1715,9 @@ class ServeEngine:
         Every iteration with work in flight is one ``serve.step`` profiler
         span (``step_num`` = ``stats.steps``) holding its host phases in
         order: ``serve.admit``, ``serve.stage``, ``serve.dispatch``
-        (args ``step``, ``chunks``, ``active``), ``serve.sync`` (the
+        (args ``step``, ``chunks``, ``active``, and ``kv``: ``in_place``
+        where the step keeps the stacked cache in place, else ``sliced``),
+        ``serve.sync`` (the
         logits' copy to the host; a second one for the prompt logits),
         ``serve.sample`` and ``serve.first_token`` (arg ``rids``).  An
         iteration with nothing in flight and nothing arrived records none
@@ -1666,6 +1729,7 @@ class ServeEngine:
         paged = pool is not None
         is_moe = self.cfg.moe is not None
         C = self.chunk_rows()
+        kv_form = "in_place" if self.kv_in_place else "sliced"
         if paged:
             # the pool persists across runs (prefix cache survives); this
             # run's stats report the deltas
@@ -1815,7 +1879,7 @@ class ServeEngine:
                                                             np.int32)),
                             ch_tokens=jnp.asarray(ch_tok))
                 with _span("serve.dispatch", step=step_i, chunks=n,
-                           active=n_active):
+                           active=n_active, kv=kv_form):
                     ret = self._cb_step(n)(
                         self._step_params, cache, tokens_dev, active_dev,
                         *extra, **chunk_kw)
@@ -1823,6 +1887,8 @@ class ServeEngine:
                 pf_logits = ret[2] if n else None
 
                 stats.steps += 1
+                if self.cb_program_info[n]["kv_in_place"]:
+                    stats.kv_in_place_steps += 1
                 if n_active:
                     stats.decode_steps += 1
                     stats.slot_steps += n_active

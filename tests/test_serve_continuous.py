@@ -265,3 +265,101 @@ def test_stats_schema():
     assert {"steps", "decode_steps", "mixed_steps", "fused_mixed_steps",
             "tokens", "occupancy", "mixed_fraction"} <= set(d)
     assert st.occupancy == 0.0 and st.mixed_fraction == 0.0
+
+
+# ---------------------------------------------------------------------------
+# The KV cache in place across the layer scan (stacked contiguous runs)
+# ---------------------------------------------------------------------------
+def _stacked_cfg():
+    return dataclasses.replace(_cfg(), num_layers=2,
+                               block_pattern=("attn", "attn"))
+
+
+def _attending_params(cfg, seed=0):
+    """``lm.init`` params with the embedding at std 1/d: at ``lm.init``'s
+    std 1 the tied head copies each input token whatever attention
+    reads, so served tokens could not tell a wrong cache row."""
+    params = lm.init(cfg, jax.random.PRNGKey(seed))
+    return {**params, "embed": {"embedding": params["embed"]["embedding"]
+                                / cfg.d_model}}
+
+
+@pytest.fixture(scope="module")
+def in_place_engine():
+    from repro.serve.engine import PrefillBudget
+    cfg = _stacked_cfg()
+    params = _attending_params(cfg)
+    eng = ServeEngine(cfg, params, batch=2, max_len=48,
+                      scheduling="continuous", plan_fusion=True,
+                      prefill_budget=PrefillBudget(chunk_rows=8,
+                                                   max_coresident_chunks=2))
+    return cfg, params, eng
+
+
+def test_kv_in_place_chunked_matches_oracle(in_place_engine):
+    """A stacked config with prompts of 1, 2 and 6 chunks: the scan carries
+    the whole (L, B, S, Hkv*D) cache, and the served tokens are the
+    wavefront oracle's, token for token, with a mid-batch EOS."""
+    cfg, params, eng = in_place_engine
+    wave = ServeEngine(cfg, params, batch=2, max_len=48,
+                       scheduling="wavefront")
+    lens, budgets = (6, 15, 41), (3, 5, 4)
+    probe = wave.run(_requests(cfg, lens, budgets))
+    eos = probe[1].out_tokens[2]
+    rw = wave.run(_requests(cfg, lens, budgets, eos=eos))
+    rc = eng.run(_requests(cfg, lens, budgets, eos=eos))
+    assert [r.out_tokens for r in rc] == [r.out_tokens for r in rw]
+    st = eng.stats
+    assert eng.kv_in_place and st.prefill_chunks > 3
+    assert st.kv_in_place_steps == st.steps > 0
+    assert st.describe()["kv_in_place_steps"] == st.steps
+    assert all(info["kv_in_place"] for info in eng.cb_program_info.values())
+    run = lm.layer_runs(cfg)[0]
+    k = jax.eval_shape(eng._init_slot_cache_local)[run.name]["k"]
+    assert k.shape == (2, 2, eng.cache_len,
+                       cfg.num_kv_heads * cfg.resolved_head_dim)
+
+
+@pytest.mark.parametrize("n", [0, 1])
+def test_kv_in_place_step_under_donation(in_place_engine, n):
+    """Donated as on the chip, the step consumes its input cache (every
+    leaf reports ``is_deleted()``) and returns what the undonated step
+    returns.  On the CPU the engine's own step keeps its input."""
+    cfg, _params, eng = in_place_engine
+    B, C = eng.batch, eng.chunk_rows()
+    i32 = jnp.int32
+    tokens = jnp.asarray([3, 7], i32)
+    active = jnp.asarray([True, n == 0])
+    kw = {}
+    if n:
+        kw = dict(ch_slots=jnp.asarray([1], i32),
+                  ch_offs=jnp.asarray([C], i32),
+                  ch_valid=jnp.asarray([C - 3], i32),
+                  ch_tokens=jnp.arange(1, C + 1, dtype=i32)[None])
+
+    def fresh():
+        cache = eng._init_slot_cache()
+        cache["pos"] = jnp.asarray([5, C], i32)
+        return jax.tree.map(lambda a: a + jnp.ones((), a.dtype), cache)
+
+    kept = fresh()
+    want = eng._cb_step(n)(eng._step_params, kept, tokens, active, **kw)
+    assert not any(a.is_deleted() for a in jax.tree.leaves(kept))
+    donated = fresh()
+    step = jax.jit(eng._make_cb_step(n), donate_argnums=(1,))
+    got = step(eng._step_params, donated, tokens, active, **kw)
+    assert all(a.is_deleted() for a in jax.tree.leaves(donated))
+    for x, y in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def test_single_layer_run_keeps_sliced_form(setup, executed_engine):
+    """L == 1 keeps its per-layer form: no step counts as in place."""
+    cfg, _params, _wave, _ = setup
+    lens, budgets = PROMPT_SETS[0]
+    executed_engine.run(_requests(cfg, lens, budgets))
+    st = executed_engine.stats
+    assert not executed_engine.kv_in_place
+    assert st.steps > 0 and st.kv_in_place_steps == 0
+    assert not any(i["kv_in_place"]
+                   for i in executed_engine.cb_program_info.values())

@@ -100,6 +100,35 @@ def test_moe_executed_matches_fallback(setup, lens, budgets):
         cfg.moe.top_k * st.slot_steps * n_layers
 
 
+def test_moe_stacked_kv_in_place_matches_fallback():
+    """Two stacked MoE layers: the scan carries the cache in place beside
+    the expert-hit accumulator, and the tokens are the vmapped
+    fallback's, token for token."""
+    cfg = _cfg(num_layers=2, block_pattern=("attn", "attn"))
+    assert lm.layer_runs(cfg)[0].count == 2
+    params = lm.init(cfg, jax.random.PRNGKey(2))
+    # embedding at std 1/d, so the tokens depend on what attention reads
+    params = {**params, "embed": {"embedding": params["embed"]["embedding"]
+                                  / cfg.d_model}}
+    budget = PrefillBudget(chunk_rows=8, max_coresident_chunks=2)
+    exe = ServeEngine(cfg, params, batch=3, max_len=48,
+                      scheduling="continuous", plan_fusion=True,
+                      prefill_budget=budget)
+    fb = ServeEngine(cfg, params, batch=3, max_len=48,
+                     scheduling="continuous", prefill_budget=budget)
+    assert exe.executed and exe.kv_in_place
+    # prompts of at most 10 rows: the fallback routes a whole prompt at
+    # once, and at 20 rows its per-expert capacity (16) drops tokens the
+    # 8-row chunks (capacity 8) keep
+    lens, budgets = (10, 5, 9, 6), (4, 4, 3, 5)
+    re_ = exe.run(_requests(cfg, lens, budgets))
+    rf = fb.run(_requests(cfg, lens, budgets))
+    assert [r.out_tokens for r in re_] == [r.out_tokens for r in rf]
+    st = exe.stats
+    assert st.kv_in_place_steps == st.steps > 0
+    assert sum(st.expert_hits) == cfg.moe.top_k * st.slot_steps * 2
+
+
 def test_moe_mid_batch_eos(setup):
     cfg, _params, exe, fb = setup
     lens, budgets = (6, 9, 7, 12), (6, 6, 6, 6)

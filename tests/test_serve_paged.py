@@ -209,3 +209,11 @@ def test_tight_pool_completes_gracefully(setup):
                if reason == "pool_full"}
     assert len(served) + len(starved) >= 3, \
         (eng.stats.retirements, [len(r.out_tokens) for r in reqs])
+
+
+def test_paged_steps_keep_their_form(setup):
+    """The paged arena is not carried in place: no step counts as such."""
+    cfg, _params, _contig, paged = setup
+    paged.run(_requests(cfg, (6, 20), (3, 2), seed=29))
+    assert not paged.kv_in_place
+    assert paged.stats.steps > 0 and paged.stats.kv_in_place_steps == 0

@@ -138,6 +138,9 @@ CODE = textwrap.dedent("""
     cfg2 = dataclasses.replace(cfg, num_layers=2,
                                block_pattern=("attn", "attn"))
     params2 = lm.init(cfg2, jax.random.PRNGKey(1))
+    # embedding at std 1/d, so the tokens depend on what attention reads
+    params2 = dict(params2, embed=dict(
+        embedding=params2["embed"]["embedding"] / cfg2.d_model))
     s2 = ServeEngine(cfg2, params2, batch=2, max_len=48,
                      scheduling="continuous", plan_fusion=True,
                      prefill_budget=budget)
@@ -152,6 +155,14 @@ CODE = textwrap.dedent("""
     rng = np.random.default_rng(5); ra = s2.run(mk())
     rng = np.random.default_rng(5); rb = t2.run(mk())
     assert [r.out_tokens for r in ra] == [r.out_tokens for r in rb]
+    w2 = ServeEngine(cfg2, params2, batch=2, max_len=48,
+                     scheduling="wavefront")
+    rng = np.random.default_rng(5); rw = w2.run(mk())
+    assert [r.out_tokens for r in rw] == [r.out_tokens for r in rb]
+    # each shard carries its (L, B, S, Hkv/4 * D) cache in place
+    assert t2.kv_in_place and t2.stats.kv_in_place_steps == t2.stats.steps
+    k2 = jax.eval_shape(t2._init_slot_cache_local)["run00_attn"]["k"]
+    assert k2.shape[-1] == cfg2.num_kv_heads * cfg2.resolved_head_dim
 
     print("SHARDED SERVE OK")
 """)

@@ -11,6 +11,8 @@ runs this file loads the TPU compiler.
 """
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -140,3 +142,73 @@ def test_fused_launch_is_named_in_compiled_text(one_chip, engine):
     assert f'"launch":{json.dumps(launch)}' in text
     name = re.sub(r"\W", "_", launch, flags=re.ASCII)
     assert re.search(rf"%{name}(\.\d+)? = .*custom-call\(", text)
+
+
+_INSTR = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = [a-z0-9]+\[([0-9,]*)\]\S* "
+                    r"([\w\-]+)\(([^)]*)\)")
+
+
+def _instructions(text):
+    """name -> (result dims, opcode, operand names) of every instruction
+    of a compiled module's text, fused computations included."""
+    out = {}
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        if m:
+            dims = tuple(int(x) for x in m.group(2).split(",") if x)
+            out[m.group(1)] = (dims, m.group(3),
+                               re.findall(r"%([\w.\-]+)", m.group(4)))
+    return out
+
+
+@pytest.mark.parametrize("n", [0, 1])
+def test_cb_step_keeps_kv_cache_in_place(one_chip, engine, monkeypatch, n):
+    """The continuous step with ``n`` chunks at granite-3-2b's widths and
+    depth, as the chip compiles it: no operation reads or writes a whole
+    layer's K/V block (the slice, relayout and write-back the layer scan
+    made of it), none copies or slices the whole stacked cache, and the
+    cache arguments are donated to the cache the step returns."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    engine._cb_steps.pop(n, None)
+    try:
+        step = engine._cb_step(n)
+    finally:
+        engine._cb_steps.pop(n, None)
+    assert engine.cb_program_info[n]["kv_in_place"]
+    cfg, B, S = engine.cfg, engine.batch, engine.cache_len
+    L, Hkv, D = cfg.num_layers, cfg.num_kv_heads, cfg.resolved_head_dim
+
+    def sd(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(sd, engine.params)
+    cache = jax.tree.map(sd, jax.eval_shape(engine._init_slot_cache_local))
+    i32 = jnp.int32
+    kw = {}
+    if n:
+        C = engine.chunk_rows()
+        kw = {k: sd(jax.ShapeDtypeStruct(s, i32)) for k, s in (
+            ("ch_slots", (n,)), ("ch_offs", (n,)), ("ch_valid", (n,)),
+            ("ch_tokens", (n, C)))}
+    text = step.lower(params, cache, sd(jax.ShapeDtypeStruct((B,), i32)),
+                      sd(jax.ShapeDtypeStruct((B,), jnp.bool_)),
+                      **kw).compile().as_text()
+    assert f"decode_attn_B{B}_S{S}_H{cfg.num_heads}kv{Hkv}_L{L}" in text
+    layer = {(B, S, Hkv, D), (1, B, S, Hkv, D), (B, S, Hkv * D),
+             (1, B, S, Hkv * D)}
+    whole = {(L, B, S, Hkv, D), (L, B, S, Hkv * D)}
+    ins = _instructions(text)
+    assert len(ins) > 100
+    moves = [name for name, (dims, _op, args) in ins.items()
+             if dims in layer or any(ins.get(a, ((),))[0] in layer
+                                     for a in args)]
+    moves += [name for name, (dims, op, _args) in ins.items()
+              if dims in whole and op in ("copy", "copy-start",
+                                          "copy-done", "dynamic-slice")]
+    assert not moves
+    n_params = len(jax.tree.leaves(params))
+    # the module's first line: input_output_alias={ {out}: (arg, {}, ..
+    header = text.splitlines()[0]
+    donated = {int(p) for p in re.findall(r"\{\d+\}: \((\d+), ", header)}
+    assert donated == set(range(n_params,
+                                n_params + len(jax.tree.leaves(cache))))
