@@ -3,75 +3,59 @@ program's ``ModelConfig``, weights drawn from the seed, and the work a step
 needs (operations and bytes), counted from the model's shapes alone.
 
 Configuration files use the key names of the model's published
-``config.json`` and hold the values as they are run.  The program fixes
-some of them (the embedding scale, the attention scale, the norm epsilon,
-no residual or logit multiplier); ``model_config`` refuses a file whose
-values the program cannot run.
+``config.json`` and hold the values as they are run.  Whatever depends on
+the architecture lives in ``bench/archs/<model_type>.py``, found by the
+file's ``model_type``; adding an architecture is adding that file.  It
+gives:
+
+    Shape(c)                        sizes: ``params``, ``cache_bytes(batch,
+                                    rows)``
+    model_config(c)                 the program's ``ModelConfig``; refuses
+                                    values the program cannot run
+    step_work(c, active_pos, chunks)  (FLOPs, bytes) one serve step needs
+    forward(c, params, tokens, int8)  the float32 reference's logits,
+                                    importing nothing of the program
+    leaf_std(names, shape)          optional: the standard deviation of a
+                                    leaf that ``init_weights``'s rules do
+                                    not know (such as a router), or None
 """
 from __future__ import annotations
 
+import importlib.util
 import math
+from pathlib import Path
 
 import numpy as np
 
-BF16_BYTES = 2
-F32_BYTES = 4
+ARCH_DIR = Path(__file__).resolve().parent / "archs"
 
 
-class Shape:
-    """The sizes that the work counts and the reference need."""
+def arch(c: dict):
+    """The module of ``c``'s architecture: ``bench/archs/<model_type>.py``."""
+    kind = c.get("model_type")
+    path = ARCH_DIR / f"{kind}.py"
+    if not kind or not path.is_file():
+        raise ValueError(f"{c.get('name')}: no architecture module for "
+                         f"model_type {kind!r}; add {path}")
+    mod_spec = importlib.util.spec_from_file_location(
+        f"bench_arch_{kind.replace('.', '_').replace('-', '_')}", path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod
 
-    def __init__(self, c: dict):
-        self.d = int(c["hidden_size"])
-        self.f = int(c["intermediate_size"])
-        self.H = int(c["num_attention_heads"])
-        self.Hkv = int(c["num_key_value_heads"])
-        self.D = int(c.get("head_dim") or self.d // self.H)
-        self.L = int(c["num_hidden_layers"])
-        self.V = int(c["vocab_size"])
-        self.gated = c["hidden_act"] in ("silu", "gelu")
 
-    @property
-    def layer_weights(self) -> int:
-        """Matmul weight elements of one layer: QKV, W_o, FFN in and out."""
-        f_in = 2 * self.f if self.gated else self.f
-        return (self.d * (self.H + 2 * self.Hkv) * self.D
-                + self.H * self.D * self.d + self.d * f_in + self.f * self.d)
-
-    @property
-    def params(self) -> int:
-        """Every parameter: layers (with their two norms), the tied
-        embedding, the final norm."""
-        return (self.L * (self.layer_weights + 2 * self.d)
-                + self.V * self.d + self.d)
-
-    @property
-    def kv_row_bytes(self) -> int:
-        """Bytes of one cache row (K and V) in one layer."""
-        return 2 * self.Hkv * self.D * BF16_BYTES
+def Shape(c: dict):
+    return arch(c).Shape(c)
 
 
 def model_config(c: dict):
     """The program's ``ModelConfig`` for configuration file ``c``."""
-    from repro.configs.base import ModelConfig
+    return arch(c).model_config(c)
 
-    s = Shape(c)
-    fixed = {"embedding_multiplier": math.sqrt(s.d),
-             "attention_multiplier": 1.0 / math.sqrt(s.D),
-             "residual_multiplier": 1.0, "logits_scaling": 1.0,
-             "rms_norm_eps": 1e-6}
-    for key, want in fixed.items():
-        if not math.isclose(float(c[key]), want, rel_tol=1e-9):
-            raise ValueError(f"{c['name']}: {key}={c[key]} but the program "
-                             f"runs {want}")
-    if not c["tie_word_embeddings"]:
-        raise ValueError(f"{c['name']}: untied embeddings are not served")
-    return ModelConfig(
-        name=c["name"], family="dense", num_layers=s.L, d_model=s.d,
-        num_heads=s.H, num_kv_heads=s.Hkv, head_dim=s.D, d_ff=s.f,
-        vocab_size=s.V, activation=c["hidden_act"], norm="rmsnorm",
-        rope_theta=float(c["rope_theta"]), tie_embeddings=True,
-        dtype=c["torch_dtype"], source=c["source"])
+
+def step_work(c: dict, active_pos, chunks) -> tuple[float, float]:
+    """(FLOPs, bytes) one serve step of ``c`` needs (see the arch module)."""
+    return arch(c).step_work(c, active_pos, chunks)
 
 
 def seed_words(seed: int) -> tuple[int, int]:
@@ -80,9 +64,26 @@ def seed_words(seed: int) -> tuple[int, int]:
     return int(w[0] >> 1), int(w[1] >> 1)
 
 
-def init_weights(cfg, seed: int):
-    """Every weight of the program's parameter tree, drawn on the device
-    from ``seed`` in one jitted call, in the type it is served in.
+def draw_spec(name: str, ndim: int, axis: str):
+    """Where a leaf is drawn on a mesh: the program's tensor-parallel
+    placement (``tp_param_pspec``), except that a column-sharded matrix is
+    drawn sharded by rows.  The engine permutes those matrices' columns
+    before it places them, and a permutation of a column-sharded array
+    gathers the whole of it on every chip; of a row-sharded one it stays
+    on each chip's rows."""
+    from jax.sharding import PartitionSpec as P
+    from repro.distributed.sharding import tp_param_pspec
+
+    spec = tp_param_pspec(name, ndim, axis)
+    if ndim >= 2 and len(spec) == ndim and spec[-1] == axis:
+        return P(*([None] * (ndim - 2) + [axis, None]))
+    return spec
+
+
+def init_weights(c: dict, seed: int, mesh=None, axis: str = "model"):
+    """Every weight of the program's parameter tree for configuration
+    ``c``, drawn on the device from ``seed`` in one jitted call, in the type
+    it is served in.
 
     Matrices are normal with variance 1/fan_in (output projections half
     that), and so is the embedding (1/hidden): with the program's
@@ -91,28 +92,39 @@ def init_weights(cfg, seed: int):
     The norm scales are small random offsets (the program applies
     ``1 + scale``), so the norms' weights are exercised too.  Stacked layer leaves are drawn one layer at a time,
     which bounds the random bits held at once to one layer's.
+
+    With ``mesh``, every leaf is drawn straight into its place on the mesh
+    (``draw_spec``); the values are those of the one-device draw, bit for
+    bit.
     """
     import jax
     import jax.numpy as jnp
     from repro.models import lm
 
+    mod = arch(c)
+    cfg = mod.model_config(c)
+    hook = getattr(mod, "leaf_std", None)
     shapes = jax.eval_shape(lambda: lm.init(cfg, jax.random.PRNGKey(0)))
     paths = [p for p, _ in jax.tree_util.tree_flatten_with_path(shapes)[0]]
     treedef = jax.tree_util.tree_structure(shapes)
     stacked = lm.layer_runs(cfg)[0].count > 1
     lo, hi = seed_words(seed)
 
+    def names_of(path):
+        return [getattr(k, "key", str(k)) for k in path]
+
     def leaf(key, path, sd):
-        names = [getattr(k, "key", str(k)) for k in path]
+        names = names_of(path)
         shape = sd.shape[1:] if stacked and names[0].startswith("run") \
             else sd.shape
 
         def draw(k):
-            if names[-1] == "scale":
+            std = hook(names, shape) if hook else None
+            if std is None and names[-1] == "scale":
                 return 0.1 * jax.random.normal(k, shape, jnp.float32)
-            if names[-1] == "embedding":
+            if std is None and names[-1] == "embedding":
                 std = 1.0 / shape[1]
-            else:
+            elif std is None:
                 std = 1.0 / math.sqrt(shape[0])
                 if names[-1] in ("w_o", "w_out"):
                     std /= math.sqrt(2.0)
@@ -123,7 +135,6 @@ def init_weights(cfg, seed: int):
                                jax.random.split(key, sd.shape[0]))
         return draw(key).astype(sd.dtype)
 
-    @jax.jit
     def make():
         key = jax.random.fold_in(jax.random.key(lo), hi)
         keys = jax.random.split(key, len(paths))
@@ -131,35 +142,10 @@ def init_weights(cfg, seed: int):
                   zip(keys, paths, jax.tree_util.tree_leaves(shapes))]
         return jax.tree_util.tree_unflatten(treedef, leaves)
 
-    return make()
-
-
-def step_work(s: Shape, active_pos, chunks) -> tuple[float, float]:
-    """(FLOPs, bytes) that one serve step needs, whatever implements it.
-
-    ``active_pos``: cache position of every decoding slot (it writes row
-    ``pos`` and attends over ``pos + 1`` rows).  ``chunks``: (offset,
-    valid rows) of every prompt chunk riding the step.
-
-    FLOPs: 2 x weight elements x rows for every matmul, attention over the
-    valid context (QK^T and PV), and the head only on rows whose logits are
-    used: every decode row and one row per chunk.  Bytes: every weight
-    once (the tied embedding once, as the head), the valid K/V rows each
-    slot and chunk attends over, read once, the rows written, and the
-    logits the host reads.  Padding beyond a valid length counts nowhere.
-    """
-    n_dec = len(active_pos)
-    rows = n_dec + sum(v for _, v in chunks)
-    keys = sum(p + 1 for p in active_pos) + sum(
-        v * o + v * (v + 1) // 2 for o, v in chunks)
-    ctx_read = sum(p + 1 for p in active_pos) + sum(o + v for o, v in chunks)
-    head_rows = n_dec + len(chunks)
-    flops = (2.0 * s.layer_weights * rows * s.L
-             + 4.0 * s.H * s.D * keys * s.L
-             + 2.0 * s.V * s.d * head_rows)
-    weight_bytes = (BF16_BYTES * (s.L * s.layer_weights + s.V * s.d)
-                    + F32_BYTES * (2 * s.L * s.d + s.d))
-    nbytes = (weight_bytes
-              + s.L * s.kv_row_bytes * (ctx_read + rows)
-              + F32_BYTES * s.V * head_rows)
-    return flops, float(nbytes)
+    if mesh is None:
+        return jax.jit(make)()
+    from jax.sharding import NamedSharding
+    out = jax.tree_util.tree_unflatten(treedef, [
+        NamedSharding(mesh, draw_spec(names_of(p)[-1], len(sd.shape), axis))
+        for p, sd in zip(paths, jax.tree_util.tree_leaves(shapes))])
+    return jax.jit(make, out_shardings=out)()

@@ -319,8 +319,8 @@ def keeping_program_spans():
     kept: dict = {}
     base_load, base_reduce = trace.load, trace.reduce
 
-    def load_all(trace_dir):
-        kept["events"] = dict(base_load(trace_dir), **load(trace_dir))
+    def load_all(trace_dir, *args):
+        kept["events"] = dict(base_load(trace_dir, *args), **load(trace_dir))
         return kept["events"]
 
     def reduce_kept(events, records):

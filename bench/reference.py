@@ -1,18 +1,10 @@
 """The plain reference, and the comparison that decides ``correct``.
 
-The reference is the published architecture in float32 ``jax.numpy``
-(matmuls at ``Precision.HIGHEST``), written from the configuration file and
-importing nothing of the program: token embedding times
-``embedding_multiplier``; per layer RMSNorm, a fused QKV projection,
-rotary embedding (rotate-half form, base ``rope_theta``), causal grouped-
-query attention scaled by ``attention_multiplier``, the output projection
-added back times ``residual_multiplier``, RMSNorm, a SwiGLU FFN added back
-the same way; a final RMSNorm and the tied embedding as the head, divided
-by ``logits_scaling``.
-
-It reads the weights the benchmark drew, in the program's parameter
-layout: QKV columns ``[q | k | v]``, the FFN in-projection ``[gate | up]``,
-and each RMSNorm weight stored as its offset from 1.
+The reference is the configuration's architecture in float32 ``jax.numpy``
+(``forward`` of ``bench/archs/<model_type>.py``), written from the
+configuration file and importing nothing of the program.  It reads the
+weights the benchmark drew, in the program's parameter layout, where they
+lie: on a mesh, ``jit`` partitions it over the cell's chips.
 
 The comparison: teacher-force each sampled request's prompt and served
 tokens through the reference and read, at every served token, how far its
@@ -26,76 +18,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from bench.model import Shape
-
-
-def _forward(c: dict, params, tokens, int8: bool):
-    """(T, V) float32 logits of ``tokens`` (T,)."""
-    import jax
-    import jax.numpy as jnp
-
-    s = Shape(c)
-    f32 = jnp.float32
-    hi = jax.lax.Precision.HIGHEST
-    eps = float(c["rms_norm_eps"])
-
-    def mm(x, w):
-        w = w.astype(f32)
-        if not int8:
-            return jnp.matmul(x, w, precision=hi)
-        sw = jnp.max(jnp.abs(w), axis=0, keepdims=True) / 127.0
-        sx = jnp.max(jnp.abs(x), axis=1, keepdims=True) / 127.0
-        wq = jnp.round(w / jnp.where(sw > 0, sw, 1.0))
-        xq = jnp.round(x / jnp.where(sx > 0, sx, 1.0))
-        return jnp.matmul(xq, wq) * sx * sw       # integers: exact products
-
-    def rms(x, offset):
-        w = 1.0 + offset.astype(f32)
-        return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
-
-    T = tokens.shape[0]
-    pos = jnp.arange(T, dtype=f32)
-    half = s.D // 2
-    inv = float(c["rope_theta"]) ** (-jnp.arange(half, dtype=f32) / half)
-    ang = pos[:, None] * inv[None, :]                   # (T, half)
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-
-    def rope(x):                                        # (T, heads, D)
-        x1, x2 = x[..., :half], x[..., half:]
-        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-    rep = s.H // s.Hkv
-    causal = jnp.tril(jnp.ones((T, T), bool))
-    a_mult = float(c["attention_multiplier"])
-    r_mult = float(c["residual_multiplier"])
-
-    def layer(x, p):
-        h = rms(x, p["norm1"]["scale"])
-        qkv = mm(h, p["attn"]["w_qkv"])
-        q = rope(qkv[:, :s.H * s.D].reshape(T, s.H, s.D))
-        k = rope(qkv[:, s.H * s.D:(s.H + s.Hkv) * s.D].reshape(T, s.Hkv, s.D))
-        v = qkv[:, (s.H + s.Hkv) * s.D:].reshape(T, s.Hkv, s.D)
-        qg = q.reshape(T, s.Hkv, rep, s.D)
-        sc = jnp.einsum("thrd,shd->hrts", qg, k, precision=hi) * a_mult
-        sc = jnp.where(causal, sc, -jnp.inf)
-        pr = jax.nn.softmax(sc, axis=-1)
-        o = jnp.einsum("hrts,shd->thrd", pr, v, precision=hi)
-        x = x + r_mult * mm(o.reshape(T, s.H * s.D), p["attn"]["w_o"])
-        h2 = rms(x, p["norm2"]["scale"])
-        a = mm(h2, p["mlp"]["w_in"])
-        gate, up = a[:, :s.f], a[:, s.f:]
-        x = x + r_mult * mm(jax.nn.silu(gate) * up, p["mlp"]["w_out"])
-        return x, None
-
-    emb = params["embed"]["embedding"].astype(f32)
-    x = emb[tokens] * float(c["embedding_multiplier"])
-    run = next(k for k in params if k.startswith("run"))
-    stack = params[run]
-    if stack["attn"]["w_qkv"].ndim == 2:                 # a single layer
-        stack = jax.tree_util.tree_map(lambda a: a[None], stack)
-    x, _ = jax.lax.scan(layer, x, stack)
-    xf = rms(x, params["final_norm"]["scale"])
-    return mm(xf, emb.T) / float(c["logits_scaling"])
+from bench.model import arch
 
 
 def make_reference(c: dict):
@@ -109,16 +32,18 @@ def make_reference(c: dict):
     import jax
     import jax.numpy as jnp
 
+    forward = arch(c).forward
+
     @jax.jit
     def gaps(params, tokens, want):
-        logits = _forward(c, params, tokens, int8=False)
+        logits = forward(c, params, tokens, int8=False)
         best = jnp.max(logits, axis=-1)
         got = jnp.take_along_axis(logits, want.T, axis=-1).T   # (K, T)
         return best[None, :] - got
 
     @jax.jit
     def control_top(params, tokens):
-        return jnp.argmax(_forward(c, params, tokens, int8=True), axis=-1)
+        return jnp.argmax(forward(c, params, tokens, int8=True), axis=-1)
 
     return gaps, control_top
 

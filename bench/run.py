@@ -67,8 +67,12 @@ def check_devices(cell, peaks: dict):
 
 
 def build(cell, seed: int):
-    """Weights and the serve engine as the program builds it."""
+    """Weights and the serve engine as the program builds it.  A cell on
+    several chips serves the model tensor-parallel over a one-axis mesh
+    (``model``) of the first ``chips`` devices."""
     import jax
+    import numpy as np
+    from jax.sharding import Mesh
     from repro.core.schedule_cache import default_cache
     from repro.serve.engine import PrefillBudget, ServeEngine
 
@@ -76,21 +80,26 @@ def build(cell, seed: int):
 
     cfg = model.model_config(cell.config)
     serve = cell.config["serve"]
+    mesh = (Mesh(np.array(jax.devices()[:cell.chips]), ("model",))
+            if cell.chips > 1 else None)
     t = time.perf_counter()
-    params = jax.block_until_ready(model.init_weights(cfg, seed))
+    params = jax.block_until_ready(model.init_weights(cell.config, seed,
+                                                      mesh=mesh))
     log(f"[weights] {cfg.name}: {model.Shape(cell.config).params:,} params "
-        f"drawn on the device in {time.perf_counter() - t:.2f}s")
+        f"drawn on {cell.chips} device(s) in "
+        f"{time.perf_counter() - t:.2f}s")
     t = time.perf_counter()
     engine = ServeEngine(cfg, params, batch=serve["batch"],
                          max_len=serve["cache_rows"], plan_fusion=True,
                          schedule_cache=default_cache(),
                          scheduling="continuous",
-                         prefill_budget=PrefillBudget())
+                         prefill_budget=PrefillBudget(), mesh=mesh)
     if not engine.executed:
         raise RuntimeError(f"{cfg.name}: the step is not executed through "
                            "the fused program")
     log(f"[engine] batch {engine.batch}, cache rows {engine.cache_len}, "
-        f"prefill chunk rows {engine.chunk_rows()} (derived), planned in "
+        f"prefill chunk rows {engine.chunk_rows()} (derived), tensor "
+        f"parallel over {engine.tp_shards}, planned in "
         f"{time.perf_counter() - t:.2f}s")
     return cfg, params, engine
 
@@ -120,17 +129,32 @@ def warm(engine, seed: int) -> None:
             f"{info['interpret']}")
 
 
+def _bytes_on(leaves, dev) -> int:
+    """Bytes of the arrays ``leaves`` that lie on device ``dev``."""
+    return sum(sh.data.nbytes for x in leaves for sh in x.addressable_shards
+               if sh.device == dev)
+
+
 def memory_line(cell, params, engine) -> None:
+    """Per chip: the weights drawn, the arrays of the step's own copy of
+    them where the engine keeps one, the K/V cache, and each chip's memory
+    in use."""
     import jax
     from bench import model
-    s = model.Shape(cell.config)
-    w = sum(x.nbytes for x in jax.tree_util.tree_leaves(params))
-    kv = engine.batch * engine.cache_len * s.kv_row_bytes * s.L
-    stats = jax.devices()[0].memory_stats() or {}
-    log(f"[memory] weights {w:,} B, KV cache {kv:,} B; in use "
-        f"{stats.get('bytes_in_use', 0):,} B, peak "
-        f"{stats.get('peak_bytes_in_use', 0):,} B of "
-        f"{stats.get('bytes_limit', 0):,} B")
+    devs = jax.devices()[:cell.chips]
+    drawn = jax.tree_util.tree_leaves(params)
+    ids = {id(x) for x in drawn}
+    w = _bytes_on(drawn, devs[0])
+    step = _bytes_on([x for x in jax.tree_util.tree_leaves(
+        engine._step_params) if id(x) not in ids], devs[0])
+    kv = model.Shape(cell.config).cache_bytes(
+        engine.batch, engine.cache_len) // engine.tp_shards
+    stats = [d.memory_stats() or {} for d in devs]
+    log(f"[memory] per chip: weights {w:,} B, the step's copy {step:,} B, "
+        f"KV cache {kv:,} B; in use "
+        f"{[s.get('bytes_in_use', 0) for s in stats]} B, peak "
+        f"{[s.get('peak_bytes_in_use', 0) for s in stats]} B of "
+        f"{stats[0].get('bytes_limit', 0):,} B")
 
 
 def serve(cell, engine, args):
@@ -160,7 +184,7 @@ def serve(cell, engine, args):
 def per_layer(cell, loop, trace_dir, args, peaks) -> tuple[dict, dict]:
     from bench import model, trace
 
-    events = trace.load(trace_dir)
+    events = trace.load(trace_dir, cell.chips)
     if args.save_trace:
         Path(args.save_trace).mkdir(parents=True, exist_ok=True)
         with open(Path(args.save_trace) / f"{cell.name}.events.json",
@@ -170,16 +194,16 @@ def per_layer(cell, loop, trace_dir, args, peaks) -> tuple[dict, dict]:
                   "w") as fh:
             json.dump(trace.describe(trace_dir), fh)
     red = trace.reduce(events, loop.steps)
-    s = model.Shape(cell.config)
     for st in red["steps"]:
-        st["flops"], st["bytes"] = model.step_work(s, st["pos"],
+        st["flops"], st["bytes"] = model.step_work(cell.config, st["pos"],
                                                    st["chunks"])
     ctx = {"counters": loop.window_counters(), "batch": loop.engine.batch,
            "steps": red["steps"], "window_s": red["window_s"],
-           "busy_s": red["busy_s"], "peaks": peaks}
+           "busy_s": red["busy_s"], "chip_busy_s": red["chip_busy_s"],
+           "chips": cell.chips, "peaks": peaks}
     log(f"[trace] {red['window_s']:.3f}s traced, {len(red['steps'])} steps "
         f"matched of {len(loop.steps)} dispatched, busy "
-        f"{red['busy_s']:.3f}s")
+        f"{red['busy_s']:.3f}s (every chip: {red['chip_busy_s']})")
     metrics = {}
     for m in cell.per_layer:
         value = spec.load_metric_reader(m["name"])(ctx)
@@ -226,8 +250,9 @@ def run(cell, args, peaks: dict, devs) -> dict:
     memory_line(cell, params, engine)
     loop, trace_dir = serve(cell, engine, args)
     setup_s = loop.open - T_PROCESS
-    peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-               for d in devs[:cell.chips])
+    chip_peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+                  for d in devs[:cell.chips]]
+    peak = max(chip_peaks)
     reqs = loop.requests
     due = [r for r in reqs if r.due < loop.close]
     started = [r for r in reqs if r.token_times]
@@ -245,7 +270,7 @@ def run(cell, args, peaks: dict, devs) -> dict:
     if ttft:
         log(f"[ttft] {len(ttft)} requests due in the window, median "
             f"{np.median(ttft) * 1e3:.1f} ms")
-    log(f"[memory] peak {peak:,} B in use")
+    log(f"[memory] peak {peak:,} B in use (every chip: {chip_peaks})")
 
     result = {"correct": False, "attempted": len(due), "failed": failed,
               "metrics": {}}
@@ -264,7 +289,8 @@ def run(cell, args, peaks: dict, devs) -> dict:
     result["device"] = {"platform": dev.platform, "kind": dev.device_kind,
                         "count": len(devs), "memory_peak_bytes": int(peak)}
     if red is not None:
-        result["device"].update(busy_s=red["busy_s"],
+        busy = red["chip_busy_s"] or [red["busy_s"]]
+        result["device"].update(busy_s=sum(busy) / len(busy),
                                 window_s=red["window_s"])
         result["breakdown"] = {"device_ops": red["device_ops"],
                                "idle_gaps": red["idle_gaps"]}

@@ -2,13 +2,30 @@
 the checkout names the cells, and each cell's parts live in files of their
 own under ``bench/``:
 
-    bench/configs/<config>.json    model configuration, as it is run
+    bench/configs/<config>.json    model configuration, as it is run; its
+                                   ``model_type`` names the architecture
+    bench/archs/<model_type>.py    the architecture: ``Shape(c)`` (with
+                                   ``params`` and ``cache_bytes(batch,
+                                   rows)``), ``model_config(c)``,
+                                   ``step_work(c, active_pos, chunks)``, the
+                                   float32 reference ``forward(c, params,
+                                   tokens, int8)``, and optionally
+                                   ``leaf_std(names, shape)`` (see
+                                   ``bench/model.py``)
     bench/traffic/<traffic>.json   traffic mix: parameters of the generator
     bench/cells/<workload>.json    what belongs to one cell alone (its fixed
                                    rate, its correctness limit); optional
     bench/metrics/<metric>.py      one per-layer metric: ``read(ctx)``
 
-Adding a configuration, a mix, a cell or a metric is adding a file.
+Adding a configuration, an architecture, a mix, a cell or a metric is
+adding a file.
+
+A cell's ``chips`` places it.  On one chip the engine serves the model
+alone.  On several, ``bench/run.py`` builds a one-axis mesh (``model``) over
+the first ``chips`` devices: the weights are drawn straight into their
+places on it (``bench/model.draw_spec``), the engine serves them
+tensor-parallel, the reference runs over the same chips on the weights
+where they lie, and the trace is read for every chip (``bench/trace.py``).
 """
 from __future__ import annotations
 

@@ -4,8 +4,10 @@ idle gaps attributed to what the host was doing.
 ``load`` reads the ``.xplane.pb`` that ``jax.profiler`` wrote and keeps a
 small normalized form (JSON-serializable; the test fixture is one):
 
-    {"modules": [[name, start_ns, dur_ns], ...],       # device: programs
-     "ops":     [[name, start_ns, dur_ns, pallas], ...], # device: operations
+    {"modules": [[name, start_ns, dur_ns], ...],       # chip 0: programs
+     "ops":     [[name, start_ns, dur_ns, pallas], ...], # chip 0: operations
+     "chip_busy": [[[start_ns, end_ns], ...], ...],    # every chip: merged
+                                                       # operation intervals
      "host":    [[name, start_ns, dur_ns], ...]}       # bench.* host spans
 
 on one clock.  ``reduce`` turns it and the harness's per-step records into
@@ -22,6 +24,8 @@ import numpy as np
 STEP_MODULE = "jit_step"       # the jitted continuous-batching step
 PALLAS_TARGET = 'custom_call_target="tpu_custom_call"'
 CONTAINERS = ("while", "conditional", "call")   # ops that hold other ops
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
+               "collective-permute")            # and their -start/-done
 
 
 def _stats(ev) -> dict:
@@ -57,8 +61,22 @@ def _short(text: str) -> str:
     return f"{name} = {shape[:60]} {op}".strip()
 
 
-def load(trace_dir: str) -> dict:
-    """Normalized events of the first TPU device and the host spans."""
+def is_collective(short: str) -> bool:
+    """Whether an op (as ``load`` names it) exchanges data between chips."""
+    op = short.rsplit(" ", 1)[-1]
+    return op.removesuffix("-start").removesuffix("-done") in COLLECTIVES
+
+
+def _merged(events) -> list[list[int]]:
+    """Merged [start, end) intervals of a device's leaf operations."""
+    return [[s, e] for s, e in union(
+        [(None, int(ev.start_ns), int(ev.duration_ns)) for ev in events
+         if _opcode(ev.name) not in CONTAINERS], 0, 2 ** 63)]
+
+
+def load(trace_dir: str, chips: int = 1) -> dict:
+    """Normalized events of the first TPU device, the busy intervals of
+    the first ``chips`` TPU devices, and the host spans."""
     from jax.profiler import ProfileData
 
     paths = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
@@ -66,7 +84,7 @@ def load(trace_dir: str) -> dict:
     if not paths:
         raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
     pd = ProfileData.from_file(paths[-1])
-    out = {"modules": [], "ops": [], "host": []}
+    out = {"modules": [], "ops": [], "chip_busy": [], "host": []}
     devices = sorted((p for p in pd.planes
                       if p.name.startswith("/device:TPU:")),
                      key=lambda p: p.name)
@@ -81,6 +99,10 @@ def load(trace_dir: str) -> dict:
                                int(e.duration_ns), PALLAS_TARGET in e.name]
                               for e in line.events
                               if _opcode(e.name) not in CONTAINERS]
+    for plane in devices[:chips]:
+        out["chip_busy"].append(next(
+            (_merged(line.events) for line in plane.lines
+             if line.name == "XLA Ops"), []))
     for plane in pd.planes:
         if not plane.name.startswith("/host:"):
             continue
@@ -183,7 +205,8 @@ def _align(mods, recs) -> int:
 def match_steps(events: dict, records: list[dict]) -> list[dict]:
     """Each traced step module matched to the harness's record of the
     dispatch that launched it (modules run in dispatch order), with its
-    device time and the part of it spent in Pallas launches."""
+    device time and the parts of it spent in Pallas launches and in
+    collectives."""
     disp = sorted(int(n.rsplit(".", 1)[1]) for n, _, _ in events["host"]
                   if n.startswith("bench.dispatch."))
     if not disp:
@@ -194,25 +217,39 @@ def match_steps(events: dict, records: list[dict]) -> list[dict]:
     off = _align(mods, recs)
     if off is None:
         return []
-    pallas = sorted((s, d) for _, s, d, p in events["ops"] if p)
-    p_starts = [s for s, _ in pallas]
+    pallas = _sorted_ops(events, lambda name, p: p)
+    coll = _sorted_ops(events, lambda name, p: is_collective(name))
     steps = []
     for i, rec in enumerate(recs):
         if not 0 <= off + i < len(mods):
             continue
         s, d, _ = mods[off + i]
-        j = int(np.searchsorted(p_starts, s))
-        p_ns = 0
-        while j < len(pallas) and pallas[j][0] < s + d:
-            p_ns += min(pallas[j][0] + pallas[j][1], s + d) - pallas[j][0]
-            j += 1
-        steps.append(dict(rec, device_s=d * 1e-9, pallas_s=p_ns * 1e-9))
+        steps.append(dict(rec, device_s=d * 1e-9,
+                          pallas_s=_within(pallas, s, s + d),
+                          collective_s=_within(coll, s, s + d)))
     return steps
 
 
+def _sorted_ops(events: dict, keep) -> tuple[list, list]:
+    """(starts, (start, dur) pairs) of chip 0's ops that ``keep`` takes."""
+    ops = sorted((s, d) for name, s, d, p in events["ops"] if keep(name, p))
+    return [s for s, _ in ops], ops
+
+
+def _within(sorted_ops, lo: int, hi: int) -> float:
+    """Seconds of the ops that start in [lo, hi), cut at ``hi``."""
+    starts, ops = sorted_ops
+    j = int(np.searchsorted(starts, lo))
+    ns = 0
+    while j < len(ops) and ops[j][0] < hi:
+        ns += min(ops[j][0] + ops[j][1], hi) - ops[j][0]
+        j += 1
+    return ns * 1e-9
+
+
 def reduce(events: dict, records: list[dict]) -> dict:
-    """Window, busy time, idle attribution, top device ops and matched
-    steps."""
+    """Window, busy time (chip 0's, and every chip's), idle attribution,
+    top device ops and matched steps."""
     win = [(s, s + d) for n, s, d in events["host"] if n == "bench.window"]
     if win:
         lo, hi = win[0]
@@ -231,7 +268,11 @@ def reduce(events: dict, records: list[dict]) -> dict:
     top_ops = sorted(per_op.items(), key=lambda kv: -kv[1])[:10]
     idle = sorted(attribute(gaps, events["host"]).items(),
                   key=lambda kv: -kv[1])[:10]
+    chip_busy_s = [sum(e - s for s, e in
+                       union([[None, s, e - s] for s, e in ivs], lo, hi))
+                   * 1e-9 for ivs in events.get("chip_busy", [])]
     return {"window_s": (hi - lo) * 1e-9, "busy_s": busy_ns * 1e-9,
+            "chip_busy_s": chip_busy_s,
             "steps": match_steps(events, records),
             "device_ops": [[n, v] for n, v in top_ops],
             "idle_gaps": [[n, v] for n, v in idle]}
