@@ -1,11 +1,11 @@
 """JAX's persistent compilation cache, kept at one fixed place.
 
 ``enable()`` is called by the entry points (the ``repro.launch`` mains and
-``chip_smoke.py``); importing a module never turns the cache on.  Where
-``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and nothing here
-sets another directory.  Otherwise the cache lives in ``<checkout>/.jax_cache``
-(listed in ``.gitignore``): a fixed path, because the directory is part of
-what a later run has to find.
+the benchmark's ``bench/run.py``); importing a module never turns the
+cache on.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads
+it and nothing here sets another directory.  Otherwise the cache lives in
+``<checkout>/.jax_cache`` (listed in ``.gitignore``): a fixed path,
+because the directory is part of what a later run has to find.
 """
 from __future__ import annotations
 

@@ -160,6 +160,44 @@ def collective_bytes(hlo_text: str, n_devices: int) -> CollectiveStats:
     return stats
 
 
+def _collectives(hlo_text: str):
+    """(kind, operand types, result types) of each collective
+    (``COLLECTIVE_KINDS``) in a compiled module, in every computation: a
+    loop body once, and an asynchronous ``-start`` / ``-done`` pair
+    once."""
+    types, found = {}, []
+    for line in hlo_text.splitlines():
+        name, eq, rhs = line.strip().removeprefix("ROOT ").partition(" = ")
+        op = re.search(r" ([a-z][\w\-]*)\(", rhs) if eq else None
+        if not op:
+            continue
+        res = set(re.findall(r"(\w+)\[", rhs[:op.start()]))
+        types[name.lstrip("%")] = res
+        kind = op.group(1).removesuffix("-start")
+        if kind in COLLECTIVE_KINDS:
+            args = rhs[op.end():].split(")")[0]
+            found.append((kind, re.findall(r"%([\w.\-]+)", args), res))
+    for kind, args, res in found:
+        yield kind, set().union(*(types.get(a, ()) for a in args)), res
+
+
+def collective_counts(hlo_text: str) -> dict:
+    """How many collectives of each kind a compiled module holds."""
+    counts = dict.fromkeys(COLLECTIVE_KINDS, 0)
+    for kind, _, _ in _collectives(hlo_text):
+        counts[kind] += 1
+    return counts
+
+
+def collective_dtypes(hlo_text: str) -> list:
+    """``"<operand types>-><result types>"`` of the collectives of a
+    compiled module, each side's element types sorted and joined by
+    ``,``: ``["f32->bf16"]`` where every collective reads fp32 and writes
+    bf16."""
+    return sorted({",".join(sorted(ins)) + "->" + ",".join(sorted(out))
+                   for _, ins, out in _collectives(hlo_text)})
+
+
 @dataclasses.dataclass
 class Roofline:
     flops: float                 # per chip

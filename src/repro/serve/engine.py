@@ -44,6 +44,7 @@ variant of the decode step.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import warnings
 from dataclasses import dataclass, field
@@ -293,11 +294,26 @@ def _out_proj(x, w, axis: Optional[str] = None):
     shard's row slab: the shards' fp32 partial products sum across ``axis``
     and round to ``x.dtype`` once, as the single-device matmul does, so the
     sharded step reproduces the one-device numerics up to fp32 summation
-    order."""
+    order.  The barrier keeps the TPU compiler from rounding the partials
+    to ``x.dtype`` ahead of the all-reduce, as it otherwise does at some
+    shapes; it still folds the one rounding into the all-reduce, which
+    reads the fp32 partials and writes the ``x.dtype`` sum."""
     if axis is None:
         return x @ w
-    return jax.lax.psum(jnp.dot(x, w, preferred_element_type=jnp.float32),
-                        axis).astype(x.dtype)
+    s = jax.lax.psum(jnp.dot(x, w, preferred_element_type=jnp.float32), axis)
+    return jax.lax.optimization_barrier(s).astype(x.dtype)
+
+
+ATTN_OUT_PSUM, FFN_OUT_PSUM = "tp_attn_out_psum", "tp_ffn_out_psum"
+
+
+def _psum_scope(name: str, axis: Optional[str]):
+    """Under tensor parallelism, the named scope an output projection's
+    product and ``psum`` run in (``ATTN_OUT_PSUM`` for W_o,
+    ``FFN_OUT_PSUM`` for W_out), so a compiled program's all-reduces say
+    which projection each closes; a single-device step is left as it
+    is."""
+    return jax.named_scope(name) if axis else contextlib.nullcontext()
 
 
 def _mlp_from_h(cfg: ModelConfig, h, w_out, axis: Optional[str] = None):
@@ -313,7 +329,8 @@ def _mlp_from_h(cfg: ModelConfig, h, w_out, axis: Optional[str] = None):
         h = jnp.square(jax.nn.relu(h))
     else:
         raise ValueError(act)
-    return _out_proj(h, w_out, axis)
+    with _psum_scope(FFN_OUT_PSUM, axis):
+        return _out_proj(h, w_out, axis)
 
 
 class ServeEngine:
@@ -446,6 +463,7 @@ class ServeEngine:
         #                                             reporting accessor)
         self._cb_decode = None                      # generic vmapped fallback
         self._refill_write = None
+        self._tp_cache_init = None                  # jitted sharded cache
         self.stats = ServeStats(batch=batch)
         # the executed continuous step decodes with _step_params: the plain
         # params single-device, the shard-major-permuted copy under TP (see
@@ -880,14 +898,16 @@ class ServeEngine:
         psum_axis = axis if tp > 1 else None   # row-sharded W_o / W_out
 
         def att_put(state, o):
-            attn_out = _out_proj(o.astype(dt).reshape(B, H * D),
-                                 state["w_o"], psum_axis)
+            with _psum_scope(ATTN_OUT_PSUM, psum_axis):
+                attn_out = _out_proj(o.astype(dt).reshape(B, H * D),
+                                     state["w_o"], psum_axis)
             state = dict(state)
             state["h_mid"] = state["x"] + attn_out              # residual 1
             return state
 
         def act_put(state, h_act):
-            ff = _out_proj(h_act.astype(dt), state["w_out"], psum_axis)
+            with _psum_scope(FFN_OUT_PSUM, psum_axis):
+                ff = _out_proj(h_act.astype(dt), state["w_out"], psum_axis)
             state = dict(state)
             state["x_out"] = state["h_mid"] + ff                # residual 2
             return state
@@ -1236,11 +1256,20 @@ class ServeEngine:
     # Continuous batching: per-slot cache positions, admit/refill per token
     # ------------------------------------------------------------------
     def _init_slot_cache(self):
-        """The slot cache, on the mesh under tensor parallelism."""
-        cache = self._init_slot_cache_local()
-        if getattr(self, "tp_shards", 1) > 1:
-            cache = self._tp_place(cache, self._tp_cache_specs())
-        return cache
+        """The slot cache.  Under tensor parallelism it is made where it
+        lies on the mesh: each chip writes only its own KV heads' zeros,
+        and no chip ever holds the whole cache (at granite-3.0-8b's 32
+        slots x 2048 rows that is 10.7 GB, more than one v5e has free
+        beside the weights)."""
+        if getattr(self, "tp_shards", 1) == 1:
+            return self._init_slot_cache_local()
+        if self._tp_cache_init is None:
+            from jax.sharding import NamedSharding
+            out = jax.tree.map(lambda s: NamedSharding(self.mesh, s),
+                               self._tp_cache_specs())
+            self._tp_cache_init = jax.jit(self._init_slot_cache_local,
+                                          out_shardings=out)
+        return self._tp_cache_init()
 
     def _init_slot_cache_local(self):
         """The slot cache: ``lm.init_cache`` with the scalar wave position
@@ -1476,8 +1505,9 @@ class ServeEngine:
             new_chs = []
             for i in range(n):
                 o = state[f"pf{i}_o"].astype(dt)             # (H_l, C, D)
-                attn_out = _out_proj(o.transpose(1, 0, 2).reshape(C, -1),
-                                     p["attn"]["w_o"], psum_axis)
+                with _psum_scope(ATTN_OUT_PSUM, psum_axis):
+                    attn_out = _out_proj(o.transpose(1, 0, 2).reshape(C, -1),
+                                         p["attn"]["w_o"], psum_axis)
                 xm = chs[i] + attn_out
                 h2 = layers.apply_norm(cfg, p["norm2"], xm[None])
                 if is_moe:
@@ -1589,12 +1619,32 @@ class ServeEngine:
         cache it returns reuses the input's buffers and the K/V rows a step
         writes land where the cache lies.  The CPU's interpret mode keeps
         its input cache alive: a caller there may run a step twice on one
-        state."""
+        state.
+
+        Under tensor parallelism the first call compiles the step through
+        ``lower(...).compile()`` and reads the compiled program's
+        collectives: ``cb_program_info[n]["collectives"]`` counts them by
+        kind, ``["collective_dtypes"]`` the element types each reads and
+        writes (``f32->bf16``: fp32 partial products in, the rounded sum
+        out).  The call itself then finds that compilation in JAX's cache,
+        so no step compiles twice."""
         if n_chunks not in self._cb_steps:
             step = self._make_cb_step(n_chunks)
             donate = () if self.cb_program_info[n_chunks]["interpret"] \
                 else (1,)
-            self._cb_steps[n_chunks] = jax.jit(step, donate_argnums=donate)
+            jitted = jax.jit(step, donate_argnums=donate)
+            self._cb_steps[n_chunks] = jitted
+            if self.tp_shards > 1:
+                def first_call(*args, **kw):
+                    from repro.distributed import hlo_analysis
+                    text = jitted.lower(*args, **kw).compile().as_text()
+                    info = self.cb_program_info[n_chunks]
+                    info["collectives"] = hlo_analysis.collective_counts(text)
+                    info["collective_dtypes"] = \
+                        hlo_analysis.collective_dtypes(text)
+                    self._cb_steps[n_chunks] = jitted
+                    return jitted(*args, **kw)
+                self._cb_steps[n_chunks] = first_call
         return self._cb_steps[n_chunks]
 
     # ------------------------------------------------------------------
@@ -1715,8 +1765,9 @@ class ServeEngine:
         Every iteration with work in flight is one ``serve.step`` profiler
         span (``step_num`` = ``stats.steps``) holding its host phases in
         order: ``serve.admit``, ``serve.stage``, ``serve.dispatch``
-        (args ``step``, ``chunks``, ``active``, and ``kv``: ``in_place``
-        where the step keeps the stacked cache in place, else ``sliced``),
+        (args ``step``, ``chunks``, ``active``, ``kv``: ``in_place``
+        where the step keeps the stacked cache in place, else ``sliced``,
+        and ``shards``: the tensor-parallel shards the step runs over),
         ``serve.sync`` (the
         logits' copy to the host; a second one for the prompt logits),
         ``serve.sample`` and ``serve.first_token`` (arg ``rids``).  An
@@ -1879,7 +1930,8 @@ class ServeEngine:
                                                             np.int32)),
                             ch_tokens=jnp.asarray(ch_tok))
                 with _span("serve.dispatch", step=step_i, chunks=n,
-                           active=n_active, kv=kv_form):
+                           active=n_active, kv=kv_form,
+                           shards=self.tp_shards):
                     ret = self._cb_step(n)(
                         self._step_params, cache, tokens_dev, active_dev,
                         *extra, **chunk_kw)

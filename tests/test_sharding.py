@@ -177,3 +177,27 @@ def test_collective_parser():
     assert st.by_kind["all-reduce"] == pytest.approx(2 * 1024 * 256 * 4 * 0.5)
     assert st.by_kind["all-gather"] == pytest.approx(64 * 128 * 2 * 0.75)
     assert st.count == 3
+
+
+def test_collective_counts_and_dtypes():
+    """What the tensor-parallel engine reads off a compiled step: the
+    collectives by kind (an async pair once), and the element types each
+    reads and writes (the TPU compiler folds a psum's rounding to bf16
+    into the all-reduce, which then reads fp32 and writes bf16)."""
+    from repro.distributed.hlo_analysis import (collective_counts,
+                                                collective_dtypes)
+    hlo = """
+      %fusion.76 = f32[32,4096]{1,0:T(8,128)S(1)} fusion(%a), kind=kOutput, calls=%f, metadata={op_name="jit(step)/shard_map/while/body/tp_attn_out_psum/dot_general" stack_frame_id=65}
+      %all-reduce.2 = bf16[32,4096]{1,0:T(8,128)(2,1)S(1)} all-reduce(%fusion.76), channel_id=1, replica_groups={{0,1,2,3}}, to_apply=%sum
+      %b = f32[8]{0} parameter(1)
+      %ars = (f32[8]{0}, f32[8]{0}) all-reduce-start(%b), replica_groups={{0,1}}
+      %ard = f32[8]{0} all-reduce-done(%ars)
+      %y = bf16[16,128]{1,0} parameter(0)
+      ROOT %ag = bf16[64,128]{1,0} all-gather(%y), replica_groups={{0,1,2,3}}
+      %noise = s32[8]{0} add(%a, %b), metadata={op_name="jit(step)/tp_ffn_out_psum/add"}
+    """
+    assert collective_counts(hlo) == {"all-reduce": 2, "all-gather": 1,
+                                      "reduce-scatter": 0, "all-to-all": 0,
+                                      "collective-permute": 0}
+    assert collective_dtypes(hlo) == ["bf16->bf16", "f32->bf16",
+                                      "f32->f32"]

@@ -25,9 +25,9 @@ from repro.serve.engine import PrefillBudget, ServeEngine
 
 HBM_BYTES = 16 * 10 ** 9        # one v5e chip
 
-# the chip_smoke.py serving shape: batch 8, 300-token prompts + 16 new
-# tokens (max_len 325 -> a 384-row cache), 256-row chunk budget, two
-# co-resident chunks
+# a small serving shape: batch 8, 300-token prompts + 16 new tokens
+# (max_len 325 -> a 384-row cache), 256-row chunk budget, two co-resident
+# chunks
 SMOKE = dict(batch=8, max_len=325,
              prefill_budget=PrefillBudget(chunk_rows=256,
                                           max_coresident_chunks=2))
@@ -212,3 +212,68 @@ def test_cb_step_keeps_kv_cache_in_place(one_chip, engine, monkeypatch, n):
     donated = {int(p) for p in re.findall(r"\{\d+\}: \((\d+), ", header)}
     assert donated == set(range(n_params,
                                 n_params + len(jax.tree.leaves(cache))))
+
+
+@pytest.fixture(scope="module")
+def tp_engine(one_chip):
+    """A tensor-parallel engine over the four described chips of the
+    v5e:2x2 at granite-3-2b's widths, two layers deep: 8 query and 2 KV
+    heads a chip.  Its weights are shapes only, so the engine's own
+    permutation and placement of them are skipped."""
+    import dataclasses
+
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    mesh = Mesh(np.array(topo.devices[:4]), ("model",))
+    cfg = dataclasses.replace(get_config("granite-3-2b"), num_layers=2)
+    params = jax.eval_shape(lambda: lm.init(cfg, jax.random.PRNGKey(0)))
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ServeEngine, "_tp_permuted_params", lambda self:
+                   self.params)
+        mp.setattr(ServeEngine, "_tp_place", lambda self, tree, specs: tree)
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        eng = ServeEngine(cfg, params, plan_fusion=True, mesh=mesh, **SMOKE)
+        yield eng, mesh
+
+
+@pytest.mark.parametrize("n", [0, 1, 2])
+def test_tp_step_collectives_for_v5e(tp_engine, n):
+    """The tensor-parallel continuous step with ``n`` chunks, as the chip
+    compiles it, holds all-reduces and nothing that gathers: the two
+    output projections' ``psum``s in the layer loop (the compiler may
+    merge a projection's decode-row and chunk ``psum``s).  Each reads the
+    shards' fp32 partial products; the compiler folds the rounding to
+    bf16 into the all-reduce, which writes the bf16 sum."""
+    from jax.sharding import NamedSharding, PartitionSpec
+    from repro.distributed import hlo_analysis
+    engine, mesh = tp_engine
+    assert engine.tp_shards == 4
+
+    def placed(tree, specs):
+        return jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, s)), tree, specs)
+
+    def sd(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh,
+                                                           PartitionSpec()))
+
+    params = placed(engine.params, engine._tp_param_specs(engine.params))
+    cache = placed(jax.eval_shape(engine._init_slot_cache_local),
+                   engine._tp_cache_specs())
+    kw = {}
+    if n:
+        kw = {k: sd(s) for k, s in (
+            ("ch_slots", (n,)), ("ch_offs", (n,)), ("ch_valid", (n,)),
+            ("ch_tokens", (n, engine.chunk_rows())))}
+    B = engine.batch
+    text = jax.jit(engine._make_cb_step(n), donate_argnums=(1,)).lower(
+        params, cache, sd((B,)), sd((B,), jnp.bool_),
+        **kw).compile().as_text()
+    counts = hlo_analysis.collective_counts(text)
+    assert 2 <= counts.pop("all-reduce") <= 2 + 2 * n
+    assert not any(counts.values())
+    assert hlo_analysis.collective_dtypes(text) == ["f32->bf16"]

@@ -118,6 +118,7 @@ def test_one_step_span_per_dispatch_with_phases_in_order(tmp_path, engine):
         disp = dict((k[0], k[3]) for k in kids)["dispatch"]
         assert disp["step"] == step and disp["chunks"] == n
         assert disp["active"] == int(disp["active"])
+        assert int(disp["shards"]) == engine.tp_shards == 1
         if "first_token" in names:
             admitted += str(dict((k[0], k[3]) for k in kids)
                             ["first_token"]["rids"]).split()
